@@ -175,8 +175,7 @@ def moment_reduction_check(gate: dict, cell, led) -> list:
     mom = led.moments
     if mom is None:
         failures.append(f"{gate['name']}: no moments channel was measured")
-    elif mom.mode == "explicit" and mom.host_kind is not None \
-            and mom.h2d_count != 2 * mom.n_leaves:
+    elif mom.h2d_count != 2 * mom.n_leaves:
         failures.append(
             f"{gate['name']}: explicit update staged {mom.h2d_count} H2D "
             f"copies for {mom.n_leaves} moment-tree leaves — the "

@@ -243,7 +243,6 @@ def _audit_moments(rep: AuditReport, cell, pstruct) -> None:
 
     def opt_fn(p, g, s):
         return adamw.apply_update(p, g, s, lr=1e-3, offload_moments=True,
-                                  moments_mode=plan.moments_mode,
                                   moments_dtype=moments_dtype)
 
     cjx = jax.make_jaxpr(opt_fn)(pstruct, pstruct, state)
@@ -255,15 +254,14 @@ def _audit_moments(rep: AuditReport, cell, pstruct) -> None:
     rep.counters["opt-update.h2d"] = facts.h2d
     rep.counters["opt-update.moment_leaves"] = n_leaves
 
-    if plan.moments_mode == "explicit":
-        # one H2D into the staged update and one D2H back per host leaf —
-        # the one-copy contract (DESIGN.md §11)
-        if facts.h2d != n_leaves or facts.d2h != n_leaves:
-            rep.add(Finding(
-                id="R1-moment-copy-count", rule="R1", trace="opt-update",
-                message=(f"explicit moments update shows {facts.h2d} H2D "
-                         f"/ {facts.d2h} D2H for {n_leaves} host moment "
-                         "leaves (expected exactly one each per leaf)")))
+    # one H2D into the staged update and one D2H back per host leaf — the
+    # one-copy contract (DESIGN.md §11)
+    if facts.h2d != n_leaves or facts.d2h != n_leaves:
+        rep.add(Finding(
+            id="R1-moment-copy-count", rule="R1", trace="opt-update",
+            message=(f"moments update shows {facts.h2d} H2D / {facts.d2h} "
+                     f"D2H for {n_leaves} host moment leaves (expected "
+                     "exactly one each per leaf)")))
     for site in facts.h2d_hazards:
         rep.add(Finding(
             id="R3-overlap-hazard", rule="R3", trace="opt-update",
@@ -293,7 +291,7 @@ def audit_cell(cell, *, data_size: int, model_size: int,
     (the same builders CI measures and serves with) over struct inputs and
     applies every applicable rule.  Returns the report; never raises on a
     finding — tracing errors are captured in ``report.error``."""
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel import runner
     from repro.parallel import specs as SP
     from repro.runtime import memledger as ml
@@ -317,7 +315,7 @@ def audit_cell(cell, *, data_size: int, model_size: int,
         _audit_act_trace(rep, cjx, "train-grad", codec=plan.offload_dtype)
 
     if (not train) or plan.pp > 1:
-        mesh = compat_make_mesh((data_size, model_size), ("data", "model"))
+        mesh = make_mesh((data_size, model_size), ("data", "model"))
         pre_fn, sstruct, _ = runner.make_prefill_step(cell, mesh)
         pstruct = {"stages": g_stage, "globals": gl}
         cjx_pre = jax.make_jaxpr(pre_fn)(pstruct, bstruct)

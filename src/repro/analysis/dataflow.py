@@ -3,7 +3,7 @@
 One traversal serves every jaxpr consumer in the repo: the memory ledger's
 tagged-byte / device_put accounting (runtime/memledger.py) and the static
 contract auditor (analysis/audit.py).  The walker is deliberately dumb and
-total — it visits every equation of every sub-jaxpr (pjit / shard_map /
+total — it visits every equation of every sub-jaxpr (jit / shard_map /
 scan / remat / custom_vjp bodies, wherever a ``Jaxpr`` or ``ClosedJaxpr``
 hides in an equation's params) exactly once, carrying:
 
@@ -22,6 +22,7 @@ from dataclasses import dataclass
 from typing import Dict, Iterator, Optional, Tuple
 
 import jax
+from jax.extend import core as jex_core
 
 # Bit widths of the sub-byte ml_dtypes: numpy's ``dtype.itemsize`` reports a
 # full byte for them (packed XLA buffers hold 2 int4s per byte), so
@@ -69,10 +70,9 @@ def aval_bytes(aval) -> int:
 
 def sub_jaxprs(v) -> Iterator[object]:
     """Yield every (open) Jaxpr reachable from one equation-param value."""
-    core = jax.core
-    if isinstance(v, core.ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, core.Jaxpr):
+    elif isinstance(v, jex_core.Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for item in v:
@@ -122,12 +122,23 @@ def iter_sites(closed_or_jaxpr, *, path: Tuple[str, ...] = (),
             yield from iter_sites(sub, path=sub_path, mult=m)
 
 
+# A traced put names its target as a ``jax.memory.Space`` (the jaxpr prints
+# ``devices=(MemorySpace.Host,)``); Space.Host is the pinned_host kind.
+_SPACE_KINDS = {jax.memory.Space.Host: "pinned_host",
+                jax.memory.Space.Device: "device"}
+
+
 def device_put_kinds_of(eqn):
     """Memory-kind list of one ``device_put`` equation (may be empty when
-    the put carries no explicit placement)."""
-    return [k for k in (getattr(d, "memory_kind", None)
-                        for d in eqn.params.get("devices", ()))
-            if k is not None]
+    the put carries no explicit placement): a ``jax.memory.Space`` target
+    maps to its kind, a sharding target gives its ``memory_kind``."""
+    kinds = []
+    for d in eqn.params.get("devices", ()):
+        k = (_SPACE_KINDS.get(d) if isinstance(d, jax.memory.Space)
+             else getattr(d, "memory_kind", None))
+        if k is not None:
+            kinds.append(k)
+    return kinds
 
 
 def walk_named(closed_or_jaxpr) -> Tuple[Dict[str, int], Dict[str, int]]:
@@ -188,7 +199,7 @@ def first_real_producer(jaxpr, var, prods: Optional[Dict] = None,
         prods = producers(jaxpr)
     seen = 0
     while True:
-        if isinstance(var, jax.core.Literal):
+        if isinstance(var, jex_core.Literal):
             return None
         eqn = prods.get(var)
         if eqn is None:
@@ -213,7 +224,7 @@ def ancestor_prims(jaxpr, var, prods: Optional[Dict] = None,
     visited = set()
     while frontier and len(visited) < limit:
         v = frontier.pop()
-        if isinstance(v, jax.core.Literal) or id(v) in visited:
+        if isinstance(v, jex_core.Literal) or id(v) in visited:
             continue
         visited.add(id(v))
         eqn = prods.get(v)
@@ -224,7 +235,7 @@ def ancestor_prims(jaxpr, var, prods: Optional[Dict] = None,
     return prims
 
 
-_WRAPPER_PRIMS = ("pjit", "shard_map", "remat2", "custom_vjp_call_jaxpr",
+_WRAPPER_PRIMS = ("jit", "shard_map", "remat2", "custom_vjp_call",
                   "custom_jvp_call", "closed_call")
 
 
@@ -239,7 +250,7 @@ def _wrapper_body(eqn):
 
 def outvar_frames(closed_or_jaxpr, index: int):
     """Resolve output ``index`` of a traced program through wrapper
-    equations (pjit / shard_map / remat) and pure layout equations to the
+    equations (jit / shard_map / remat) and pure layout equations to the
     scope that actually computes it.
 
     Returns ``(frames, scope_jaxpr, var)`` where ``frames`` is the wrapper
@@ -252,7 +263,7 @@ def outvar_frames(closed_or_jaxpr, index: int):
     steps = 0
     while steps < 10000:
         steps += 1
-        if isinstance(var, jax.core.Literal):
+        if isinstance(var, jex_core.Literal):
             return frames, jaxpr, var
         prods = producers(jaxpr)
         eqn = prods.get(var)
@@ -284,7 +295,7 @@ def cross_scope_ancestor_prims(frames, jaxpr, var, *, limit: int = 2000):
     at a scope input (a value computed by the caller and passed in).
 
     Position mapping assumes the wrapper's operands align 1:1 with the body
-    jaxpr's invars (true for pjit / shard_map / remat2); when they don't,
+    jaxpr's invars (true for jit / shard_map / remat2); when they don't,
     the hop is skipped and provenance is simply truncated there."""
     prims: set = set()
     stack = list(frames)
@@ -298,7 +309,7 @@ def cross_scope_ancestor_prims(frames, jaxpr, var, *, limit: int = 2000):
         hit_invars = []
         while frontier and budget > 0:
             v = frontier.pop()
-            if isinstance(v, jax.core.Literal) or id(v) in visited:
+            if isinstance(v, jex_core.Literal) or id(v) in visited:
                 continue
             visited.add(id(v))
             budget -= 1

@@ -177,9 +177,9 @@ class ParallelPlan:
     partition: str = "flops"   # flops | length  (SPPO sequence partitioning)
     offload: bool = True       # adaptive activation offload to pinned_host
     # offload execution form (DESIGN.md §10): "explicit" places act_off rows
-    # via memory-kind device_puts in the tick loop (staged-copy emulation on
-    # backends without host memory kinds); "xla" delegates placement to the
-    # remat offload policy (save_and_offload_only_these_names)
+    # via memory-kind device_puts in the tick loop; "xla" delegates
+    # placement to the remat offload policy
+    # (save_and_offload_only_these_names)
     offload_mode: str = "explicit"
     # backward-reload placement on the explicit path (DESIGN.md §12):
     # "ahead" = tick-level custom_vjp seam issuing chunk i's H2D one event
@@ -193,11 +193,9 @@ class ParallelPlan:
     zero1: bool = True         # shard optimizer states over dp (and pod)
     opt_dtype: str = "float32"  # moment dtype; deepseek uses bfloat16
     # executed optimizer-state offload (DESIGN.md §11): AdamW m/v live in
-    # host memory kinds between steps.  moments_mode "explicit" stages one
-    # H2D per moment leaf into the device update and one D2H back;
-    # "xla" (legacy) keeps host-committed shardings and lets XLA stream.
+    # pinned_host memory between steps; the update stages one H2D per
+    # moment leaf into the device update and one D2H back.
     offload_moments: bool = False
-    moments_mode: str = "explicit"
     # compressed host residency (DESIGN.md §14): quantize the executed
     # offload channels across the host link — act_off rows (offload_dtype)
     # and the AdamW m/v moments (moments_dtype) — as fp8_e4m3 or int8 wire
@@ -236,17 +234,13 @@ class ParallelPlan:
             f"offload_mode({self.offload_mode!r}) must be explicit|xla")
         assert self.prefetch in ("ahead", "sync"), (
             f"prefetch({self.prefetch!r}) must be ahead|sync")
-        assert self.moments_mode in ("explicit", "xla"), (
-            f"moments_mode({self.moments_mode!r}) must be explicit|xla")
         assert self.offload_dtype in ("none", "fp8", "int8"), (
             f"offload_dtype({self.offload_dtype!r}) must be none|fp8|int8")
         assert self.moments_dtype in ("none", "fp8", "int8"), (
             f"moments_dtype({self.moments_dtype!r}) must be none|fp8|int8")
-        assert self.moments_dtype == "none" or (
-            self.offload_moments and self.moments_mode == "explicit"), (
-            "moments_dtype compression requires offload_moments with "
-            "moments_mode='explicit' (there is no host channel to compress "
-            "otherwise)")
+        assert self.moments_dtype == "none" or self.offload_moments, (
+            "moments_dtype compression requires offload_moments (there is "
+            "no host channel to compress otherwise)")
         assert self.attn_mode in ("gather_q", "gather_kv", "auto", "ring",
                                   "local"), (
             f"attn_mode({self.attn_mode!r}) must be "

@@ -23,7 +23,7 @@ simulated by ``peak_memory`` and asserted in tests.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -224,25 +224,21 @@ def null_tag(t):
 # device (H2D).  Double-buffering falls out of the dataflow: chunk i's D2H
 # depends only on chunk i's forward, so it can overlap chunk i+1's compute,
 # and the H2D is issued by the autodiff exactly at chunk i's backward.
-# DESIGN.md §10 records the contract and the CPU fallback semantics.  The
-# memory-kind probe and the D2H/H2D primitives are shared with the
-# optimizer-moment offload path (optim/adamw.py) via runtime/hostmem.py.
+# DESIGN.md §10 records the contract.  The memory-kind probe and the
+# D2H/H2D primitives are shared with the optimizer-moment offload path
+# (optim/adamw.py) via runtime/hostmem.py.
 
 host_memory_kind = hostmem.host_memory_kind
 
 
-def host_round_trip(t, *, host_kind: Optional[str] = "auto",
-                    name: str = OFF_NAME, codec: str = "none"):
+def host_round_trip(t, *, name: str = OFF_NAME, codec: str = "none"):
     """Route `t` through host memory with the saved residual on the host:
 
       D2H -> checkpoint_name(act_off) -> H2D
 
     Under ``jax.checkpoint(policy=save_only_these_names(...))`` the named
     host-resident copy is what gets saved; the backward's remat replays only
-    the H2D.  On backends without memory kinds the staged-copy emulation
-    keeps the identical graph structure (a named save point fenced by
-    optimization barriers, so XLA must materialize the staged buffer) —
-    on either path the round trip is a value-level identity.
+    the H2D.  The round trip is a value-level identity.
 
     With a codec the rows cross compressed: quantize before the D2H (the
     host residual is the 1-byte payload), dequantize after the H2D, and the
@@ -253,14 +249,10 @@ def host_round_trip(t, *, host_kind: Optional[str] = "auto",
     have zero tangents ⇒ dead gradients); ``residual_substitute`` makes it
     a straight-through estimator instead, primal = the reconstruction,
     cotangent routed untouched to `t`'s producers."""
-    kind = hostmem.resolve_host_kind(host_kind)
     if codec in (None, "none"):
-        if kind is None:
-            staged = checkpoint_name(jax.lax.optimization_barrier(t), name)
-            return jax.lax.optimization_barrier(staged)
-        th = hostmem.to_host(t, kind)                             # D2H
+        th = hostmem.to_host(t)                                   # D2H
         th = checkpoint_name(th, name)                            # host residual
-        return hostmem.to_device(th, kind)                        # H2D
+        return hostmem.to_device(th)                              # H2D
     payload, scale = hostmem.quantize(t, codec)
     scale = checkpoint_name(scale, scale_name_for(name))          # device-resident
     # The named host residual crosses as an int8 BYTE CONTAINER: a named
@@ -277,12 +269,8 @@ def host_round_trip(t, *, host_kind: Optional[str] = "auto",
         wire = jnp.int8
     pc = (payload if wire == jnp.int8
           else jax.lax.bitcast_convert_type(payload, jnp.int8))
-    if kind is None:
-        staged = checkpoint_name(jax.lax.optimization_barrier(pc), name)
-        pc_d = jax.lax.optimization_barrier(staged)
-    else:
-        ph = checkpoint_name(hostmem.to_host(pc, kind), name)
-        pc_d = hostmem.to_device(ph, kind)
+    ph = checkpoint_name(hostmem.to_host(pc), name)
+    pc_d = hostmem.to_device(ph)
     payload_d = (pc_d if wire == jnp.int8
                  else jax.lax.bitcast_convert_type(pc_d, wire))
     deq = hostmem.dequantize(payload_d, scale, codec, t.dtype)
@@ -290,8 +278,7 @@ def host_round_trip(t, *, host_kind: Optional[str] = "auto",
 
 
 def make_exec_tag(alpha: float, *, axis: int = 1,
-                  names: tuple = (OFF_NAME, KEEP_NAME), host_kind="auto",
-                  codec: str = "none"):
+                  names: tuple = (OFF_NAME, KEEP_NAME), codec: str = "none"):
     """Executed form of ``make_tag``: same row split, but the act_off rows
     round-trip through host memory so the transfers are real program
     dataflow rather than an XLA remat hint.  The tag is a value-level
@@ -308,18 +295,15 @@ def make_exec_tag(alpha: float, *, axis: int = 1,
         if alpha <= 0.0:
             return checkpoint_name(t, keep_name)
         if alpha >= 1.0:
-            return host_round_trip(t, host_kind=host_kind, name=off_name,
-                                   codec=codec)
+            return host_round_trip(t, name=off_name, codec=codec)
         k = split_rows(t.shape[axis], alpha)
         if k <= 0:
             return checkpoint_name(t, keep_name)
         if k >= t.shape[axis]:
-            return host_round_trip(t, host_kind=host_kind, name=off_name,
-                                   codec=codec)
+            return host_round_trip(t, name=off_name, codec=codec)
         lo = jax.lax.slice_in_dim(t, 0, k, axis=axis)
         hi = jax.lax.slice_in_dim(t, k, t.shape[axis], axis=axis)
-        lo = host_round_trip(lo, host_kind=host_kind, name=off_name,
-                             codec=codec)
+        lo = host_round_trip(lo, name=off_name, codec=codec)
         hi = checkpoint_name(hi, keep_name)
         return jax.lax.concatenate([lo, hi], dimension=axis)
 

@@ -65,8 +65,7 @@ def _flash_partial_kernel(qpos_ref, kpos_ref, qstart_ref,  # position blocks
                           q_ref, k_ref, v_ref,    # [bq*G, hd] / [bk, hd] blocks
                           o_ref, m_ref, l_ref,    # outputs
                           acc_ref, mm_ref, ll_ref,  # VMEM scratch
-                          *, causal: bool, scale: float, bq: int, bk: int,
-                          g: int, nk: int):
+                          *, causal: bool, scale: float, nk: int):
     ks = pl.program_id(2)
 
     @pl.when(ks == 0)
@@ -80,7 +79,7 @@ def _flash_partial_kernel(qpos_ref, kpos_ref, qstart_ref,  # position blocks
     v = v_ref[...].astype(jnp.float32)          # [bk, hv]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # [G*bq, bk]
 
-    s = jnp.where(_visible(qpos_ref, kpos_ref, qstart_ref, g, causal),
+    s = jnp.where(_visible(qpos_ref, kpos_ref, qstart_ref, causal),
                   s, NEG_INF)
 
     m_prev = mm_ref[...]                        # [G*bq, 1]
@@ -101,31 +100,31 @@ def _flash_partial_kernel(qpos_ref, kpos_ref, qstart_ref,  # position blocks
         l_ref[...] = ll_ref[...].astype(l_ref.dtype)
 
 
-def _visible(qpos_ref, kpos_ref, qstart_ref, g: int, causal: bool):
+def _visible(qpos_ref, kpos_ref, qstart_ref, causal: bool):
     """[G*bq, bk] visibility mask — identical in forward and backward.
-    ``qstart_ref`` is the per-query segment window (packed-document
-    blocking, [bq] int32 per batch row): a kv slot is visible only when
+    Query positions arrive already folded like the q rows ([G*bq, 1]
+    columns, see ``_fold_rows``) and kv positions as a lane-dense [1, bk]
+    row, so the mask is a plain broadcast compare with no in-kernel
+    relayout.  ``qstart_ref`` is the per-query segment window
+    (packed-document blocking): a kv slot is visible only when
     kv_pos >= q_start.  Zeros degenerate to the plain positional mask;
     PAD_POS marks dead (padding) query rows — no real kv slot reaches
     2**30, so those rows mask fully."""
-    qpos = qpos_ref[...]                        # [bq] int32
-    kpos = kpos_ref[...]                        # [bk] int32
-    qpos_g = jnp.tile(qpos, (g,))               # [G*bq] — heads share positions
-    qstart_g = jnp.tile(qstart_ref[...], (g,))  # [G*bq] — per batch row
-    valid = (kpos[None, :] != PAD_POS)
+    qpos = qpos_ref[...]                        # [G*bq, 1] int32
+    kpos = kpos_ref[...]                        # [1, bk] int32
+    valid = (kpos != PAD_POS) & (kpos >= qstart_ref[...])
     if causal:
-        valid = valid & (qpos_g[:, None] >= kpos[None, :])
-    valid = valid & (kpos[None, :] >= qstart_g[:, None])
+        valid = valid & (qpos >= kpos)
     return valid
 
 
 def _recompute_p_ds(qpos_ref, kpos_ref, qstart_ref, q, k, v, do, m, dl,
-                    *, causal: bool, scale: float, g: int):
+                    *, causal: bool, scale: float):
     """Shared backward block math: recompute p from the saved logsumexp row
     statistic, then dS = P ∘ (dO·Vᵀ + dl).  m is treated as a constant (the
     gradient-frozen max statistic, see module docstring)."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-    s = jnp.where(_visible(qpos_ref, kpos_ref, qstart_ref, g, causal),
+    s = jnp.where(_visible(qpos_ref, kpos_ref, qstart_ref, causal),
                   s, NEG_INF)
     # fully-masked rows carry m == NEG_INF; exp(NEG_INF - NEG_INF) would be 1
     safe = m > NEG_INF / 2                       # [G*bq, 1]
@@ -137,7 +136,7 @@ def _recompute_p_ds(qpos_ref, kpos_ref, qstart_ref, q, k, v, do, m, dl,
 def _flash_bwd_dq_kernel(qpos_ref, kpos_ref, qstart_ref, q_ref, k_ref, v_ref,
                          do_ref, m_ref, dl_ref,
                          dq_ref, dq_acc,
-                         *, causal: bool, scale: float, g: int, nk: int):
+                         *, causal: bool, scale: float, nk: int):
     ks = pl.program_id(2)
 
     @pl.when(ks == 0)
@@ -150,7 +149,7 @@ def _flash_bwd_dq_kernel(qpos_ref, kpos_ref, qstart_ref, q_ref, k_ref, v_ref,
     do = do_ref[...].astype(jnp.float32)
     _, ds = _recompute_p_ds(qpos_ref, kpos_ref, qstart_ref, q, k, v, do,
                             m_ref[...], dl_ref[...],
-                            causal=causal, scale=scale, g=g)
+                            causal=causal, scale=scale)
     dq_acc[...] += jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ()))) * scale
 
@@ -162,7 +161,7 @@ def _flash_bwd_dq_kernel(qpos_ref, kpos_ref, qstart_ref, q_ref, k_ref, v_ref,
 def _flash_bwd_dkv_kernel(qpos_ref, kpos_ref, qstart_ref, q_ref, k_ref, v_ref,
                           do_ref, m_ref, dl_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc,
-                          *, causal: bool, scale: float, g: int, nq: int):
+                          *, causal: bool, scale: float, nq: int):
     qs = pl.program_id(2)
 
     @pl.when(qs == 0)
@@ -176,7 +175,7 @@ def _flash_bwd_dkv_kernel(qpos_ref, kpos_ref, qstart_ref, q_ref, k_ref, v_ref,
     do = do_ref[...].astype(jnp.float32)
     p, ds = _recompute_p_ds(qpos_ref, kpos_ref, qstart_ref, q, k, v, do,
                             m_ref[...], dl_ref[...],
-                            causal=causal, scale=scale, g=g)
+                            causal=causal, scale=scale)
     # row reductions over the G*bq folded q rows sum the GQA group for free
     dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
     dk_acc[...] += jax.lax.dot_general(
@@ -235,6 +234,31 @@ def _fold_kv(x, B, Hkv, Sp, last):
     return x.transpose(0, 2, 1, 3).reshape(B * Hkv, Sp, last)
 
 
+def _fold_rows(x, G, nq, bq):
+    """Per-query int32 [B, Tqp] -> [B, nq, G*bq, 1]: the value of folded q
+    row ``g*bq + t`` (``_fold_q_like``'s row order), one column per q block,
+    so the kernel reads it in the same [G*bq, 1] layout as its m/l rows."""
+    B = x.shape[0]
+    x = jnp.broadcast_to(x.reshape(B, nq, 1, bq), (B, nq, G, bq))
+    return x.reshape(B, nq, G * bq, 1)
+
+
+def _pos_specs(G, bq, bk, Hkv, kv_inner: bool):
+    """BlockSpecs of (q_pos, kv_pos, q_start) for a grid whose axes are
+    (B*Hkv, q block, kv block) when `kv_inner`, else (B*Hkv, kv block,
+    q block).  q positions vary per batch row (paged decode gives every row
+    its own position; packed layouts differ row to row): grid axis 0 is
+    B*Hkv, so row = b // Hkv.  kv positions are one lane-dense [1, Sp] row."""
+    if kv_inner:
+        qmap = lambda b, i, j: (b // Hkv, i, 0, 0)
+        kmap = lambda b, i, j: (0, j)
+    else:
+        qmap = lambda b, j, i: (b // Hkv, i, 0, 0)
+        kmap = lambda b, j, i: (0, j)
+    qspec = pl.BlockSpec((None, None, G * bq, 1), qmap)
+    return [qspec, pl.BlockSpec((1, bk), kmap), qspec]
+
+
 # ---------------------------------------------------------------------------
 # Forward / backward pallas_call wrappers
 # ---------------------------------------------------------------------------
@@ -253,20 +277,16 @@ def _fwd_impl(q, k, v, q_pos, kv_pos, q_start, causal, scale, block_q,
     qg = _fold_q_like(q, B, Hkv, G, nq, bq, hdk)
     kg = _fold_kv(k, B, Hkv, Sp, hdk)
     vg = _fold_kv(v, B, Hkv, Sp, hdv)
+    pos = (_fold_rows(q_pos, G, nq, bq), kv_pos.reshape(1, Sp),
+           _fold_rows(q_start, G, nq, bq))
 
     grid = (B * Hkv, nq, nk)
     kern = functools.partial(_flash_partial_kernel, causal=causal,
-                             scale=scale, bq=bq, bk=bk, g=G, nk=nk)
+                             scale=scale, nk=nk)
     o, m, l = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            # q_pos and q_start vary per batch row (paged decode gives every
-            # row its own position; packed layouts differ row to row): grid
-            # axis 0 is B*Hkv, so row = b // Hkv
-            pl.BlockSpec((None, bq), lambda b, i, j, Hkv=Hkv: (b // Hkv, i)),
-            pl.BlockSpec((bk,), lambda b, i, j: (j,)),                  # kv_pos
-            pl.BlockSpec((None, bq), lambda b, i, j, Hkv=Hkv: (b // Hkv, i)),
+        in_specs=_pos_specs(G, bq, bk, Hkv, kv_inner=True) + [
             pl.BlockSpec((None, None, G * bq, hdk), lambda b, i, j: (b, i, 0, 0)),
             pl.BlockSpec((None, bk, hdk), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((None, bk, hdv), lambda b, i, j: (b, j, 0)),
@@ -287,7 +307,7 @@ def _fwd_impl(q, k, v, q_pos, kv_pos, q_start, causal, scale, block_q,
             pltpu.VMEM((G * bq, 1), jnp.float32),     # running sum
         ],
         interpret=interpret,
-    )(q_pos, kv_pos, q_start, qg, kg, vg)
+    )(*pos, qg, kg, vg)
 
     o = _unfold_q_like(o, B, Hkv, G, nq, bq, hdv, Tq)
     m = _unfold_q_like(m, B, Hkv, G, nq, bq, 1, Tq)[..., 0]
@@ -325,17 +345,15 @@ def _bwd_impl(q, k, v, q_pos, kv_pos, q_start, do, m, dl, causal, scale,
     dog = _fold_q_like(do.astype(jnp.float32), B, Hkv, G, nq, bq, hdv)
     mg = _fold_q_like(m[..., None], B, Hkv, G, nq, bq, 1)
     dlg = _fold_q_like(dl.astype(jnp.float32)[..., None], B, Hkv, G, nq, bq, 1)
-    qpos_b = q_pos
+    pos = (_fold_rows(q_pos, G, nq, bq), kv_pos.reshape(1, Sp),
+           _fold_rows(q_start, G, nq, bq))
 
     # --- dq: forward's grid, KV innermost, dq accumulates in scratch
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
-                          g=G, nk=nk),
+                          nk=nk),
         grid=(B * Hkv, nq, nk),
-        in_specs=[
-            pl.BlockSpec((None, bq), lambda b, i, j, Hkv=Hkv: (b // Hkv, i)),
-            pl.BlockSpec((bk,), lambda b, i, j: (j,)),
-            pl.BlockSpec((None, bq), lambda b, i, j, Hkv=Hkv: (b // Hkv, i)),
+        in_specs=_pos_specs(G, bq, bk, Hkv, kv_inner=True) + [
             pl.BlockSpec((None, None, G * bq, hdk), lambda b, i, j: (b, i, 0, 0)),
             pl.BlockSpec((None, bk, hdk), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((None, bk, hdv), lambda b, i, j: (b, j, 0)),
@@ -349,17 +367,14 @@ def _bwd_impl(q, k, v, q_pos, kv_pos, q_start, do, m, dl, causal, scale,
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((G * bq, hdk), jnp.float32)],
         interpret=interpret,
-    )(qpos_b, kv_pos, q_start, qg, kg, vg, dog, mg, dlg)
+    )(*pos, qg, kg, vg, dog, mg, dlg)
 
     # --- dk/dv: transposed grid, q innermost, dk/dv accumulate in scratch
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
-                          g=G, nq=nq),
+                          nq=nq),
         grid=(B * Hkv, nk, nq),
-        in_specs=[
-            pl.BlockSpec((None, bq), lambda b, j, i, Hkv=Hkv: (b // Hkv, i)),
-            pl.BlockSpec((bk,), lambda b, j, i: (j,)),
-            pl.BlockSpec((None, bq), lambda b, j, i, Hkv=Hkv: (b // Hkv, i)),
+        in_specs=_pos_specs(G, bq, bk, Hkv, kv_inner=False) + [
             pl.BlockSpec((None, None, G * bq, hdk), lambda b, j, i: (b, i, 0, 0)),
             pl.BlockSpec((None, bk, hdk), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((None, bk, hdv), lambda b, j, i: (b, j, 0)),
@@ -380,7 +395,7 @@ def _bwd_impl(q, k, v, q_pos, kv_pos, q_start, do, m, dl, causal, scale,
             pltpu.VMEM((bk, hdv), jnp.float32),
         ],
         interpret=interpret,
-    )(qpos_b, kv_pos, q_start, qg, kg, vg, dog, mg, dlg)
+    )(*pos, qg, kg, vg, dog, mg, dlg)
 
     dq = _unfold_q_like(dq, B, Hkv, G, nq, bq, hdk, Tq)
 
@@ -431,7 +446,7 @@ _flash_partial.defvjp(_flash_partial_fwd, _flash_partial_bwd)
 
 def flash_attention_partial(q, k, v, q_pos, kv_pos, *, causal=True,
                             scale=None, block_q=128, block_k=128,
-                            interpret=True, q_start=None):
+                            interpret=False, q_start=None):
     """Pallas partial flash attention (differentiable in q, k, v).
 
     q: [B, Tq, H, hd_k]; k: [B, S, Hkv, hd_k]; v: [B, S, Hkv, hd_v]

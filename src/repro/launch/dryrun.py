@@ -123,9 +123,8 @@ def run_cell(arch: str, shape_name: str, mesh, *, verbose=True):
             pods=dims["pods"])
         # plan-driven host residency (DESIGN.md §11): the big-model plans
         # set offload_moments, and the dry-run prices the same placement
-        # the executed path deploys — the "auto" probe resolves to the
-        # backend's supported host kind (pinned_host on the TPU target,
-        # unpinned_host on this CPU container), exactly as init_state does
+        # the executed path deploys — pinned_host, the kind every backend
+        # (TPU, GPU and the CPU) exposes, exactly as init_state does
         moment_shard = SP.moment_shardings(
             mesh, oshard_specs,
             offload_moments=cell.plan.offload_moments)

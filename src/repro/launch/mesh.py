@@ -9,29 +9,31 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: `axis_types` (and AxisType) only
-    exist on newer jax; older releases default every axis to Auto anyway."""
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    except (AttributeError, TypeError):  # jax < 0.5: no AxisType
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    GSPMD; the code annotates with PartitionSpecs, not explicit types) over
+    `devices` (default: all devices, first ``prod(shape)`` of them)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_test_mesh(data: int = 4, model: int = 2, pods: int = 1):
-    """Small mesh for CPU integration tests."""
+def make_test_mesh(data: int = 4, model: int = 2, pods: int = 1,
+                   devices=None):
+    """Small mesh over the first ``pods*data*model`` of `devices` (default:
+    ``jax.devices()``), so a 1x1 mesh runs on a host with more chips."""
+    if devices is None:
+        devices = jax.devices()[:pods * data * model]
     if pods > 1:
-        return compat_make_mesh((pods, data, model),
-                                ("pod", "data", "model"))
-    return compat_make_mesh((data, model), ("data", "model"))
+        return make_mesh((pods, data, model), ("pod", "data", "model"),
+                         devices)
+    return make_mesh((data, model), ("data", "model"), devices)
 
 
 def mesh_dims(mesh) -> dict:

@@ -19,7 +19,7 @@ fused Pallas backward kernels' custom_vjp or the jnp scan's autodiff — and
 every max statistic is gradient-frozen before the pmax/psum merge (pmax has
 no VJP; the m-dependence cancels exactly in the o/l ratio, see
 kernels/ref.py), so ∂loss/∂{q,k,v} flow through the exp-rescaled o and l
-psums alone.  ``REPRO_USE_PALLAS=1`` training therefore runs the identical
+psums alone.  Training on the Pallas backend therefore runs the identical
 code path as serve.
 """
 from __future__ import annotations

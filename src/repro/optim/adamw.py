@@ -7,13 +7,11 @@ the big-model plans (DESIGN.md §4, §11):
   * ``offload_moments``: keep ``AdamWState.m/v`` resident in host memory
     (ZeRO-Offload analogue — the same host memory kinds and D2H/H2D
     primitives the activation offload path uses, runtime/hostmem.py).
-    Since PR 4 this is *executed dataflow*, not a sharding hint:
-    ``init_state`` births the moments in host space (no device allocation),
-    and ``apply_update`` under ``moments_mode="explicit"`` stages exactly
-    one H2D per moment leaf, computes the fp32 update on device, and writes
-    the new moments back with one D2H per leaf.  ``moments_mode="xla"``
-    is the legacy path: the moments stay host-committed through their
-    shardings and XLA streams them through HBM during the update.
+    This is *executed dataflow*, not a sharding hint: ``init_state``
+    births the moments in host space (no device allocation), and
+    ``apply_update`` stages exactly one H2D per moment leaf, computes the
+    fp32 update on device, and writes the new moments back with one D2H
+    per leaf — one leaf pair on device at a time.
   * ZeRO-1 across the `pod` axis is expressed through the moment shardings
     built in parallel/specs.py.
 
@@ -59,7 +57,7 @@ class AdamWState(NamedTuple):
 
 
 def init_state(params, opt_dtype=jnp.float32, *, offload_moments: bool = False,
-               host_kind="auto", moments_dtype: str = "none") -> AdamWState:
+               moments_dtype: str = "none") -> AdamWState:
     """Zero moments, placed where they will live.
 
     With ``offload_moments`` the zeros are *born in host memory*
@@ -76,7 +74,6 @@ def init_state(params, opt_dtype=jnp.float32, *, offload_moments: bool = False,
         assert offload_moments, (
             "moments_dtype compression requires offload_moments (there is "
             "no host channel to compress otherwise)")
-        kind = hostmem.resolve_host_kind(host_kind)
         wire = hostmem.codec_wire_dtype(moments_dtype)
 
         def zeros(p):
@@ -84,15 +81,13 @@ def init_state(params, opt_dtype=jnp.float32, *, offload_moments: bool = False,
             # the scale can't inherit p's sharding verbatim: its trailing
             # dim is 1, so a last-axis-sharded param needs the partition
             # dropped there (row_scale_sharding)
-            ssh = (hostmem.row_scale_sharding(p, kind)
-                   if kind is not None and not isinstance(p, jax.core.Tracer)
-                   else None)
-            return (hostmem.host_zeros(p.shape, wire, kind, like=p),
-                    hostmem.host_zeros(sshape, jnp.float32, kind, like=p,
+            ssh = (None if isinstance(p, jax.core.Tracer) else
+                   hostmem.row_scale_sharding(p, hostmem.host_memory_kind()))
+            return (hostmem.host_zeros(p.shape, wire, like=p),
+                    hostmem.host_zeros(sshape, jnp.float32, like=p,
                                        sharding=ssh))
     elif offload_moments:
-        kind = hostmem.resolve_host_kind(host_kind)
-        zeros = lambda p: hostmem.host_zeros(p.shape, opt_dtype, kind, like=p)
+        zeros = lambda p: hostmem.host_zeros(p.shape, opt_dtype, like=p)
     else:
         zeros = lambda p: jnp.zeros(p.shape, opt_dtype)
     return AdamWState(step=jnp.zeros((), jnp.int32),
@@ -116,18 +111,20 @@ def global_norm(tree) -> jax.Array:
 def apply_update(params, grads, state: AdamWState, *, lr, b1=0.9, b2=0.95,
                  eps=1e-8, weight_decay=0.1, clip_norm=1.0,
                  offload_moments: bool = False,
-                 moments_mode: str = "explicit", host_kind="auto",
                  moments_dtype: str = "none",
-                 probe: Optional[callable] = None):
+                 probe: Optional[callable] = None,
+                 grad_norm: Optional[jax.Array] = None):
     """One AdamW step. Returns (new_params, new_state, metrics).
 
-    offload_moments + moments_mode="explicit": per moment leaf, exactly one
-    H2D device_put brings the host-resident moment on device, the fp32
-    update runs there, and one D2H writes the new moment back to host —
-    the round trip is value-level identity, so offload on/off updates are
-    equal (tests/test_opt_offload.py).  moments_mode="xla" keeps the legacy
-    behavior: no explicit copies; placement/streaming delegated to XLA via
-    the moments' committed host shardings.
+    grad_norm: the global norm that clipping uses, for a gradient layout
+    that stores some leaves more than once (the dp replicas of a pipeline
+    stage); default ``global_norm(grads)``.
+
+    offload_moments: per moment leaf, exactly one H2D device_put brings
+    the host-resident moment on device, the fp32 update runs there, and one
+    D2H writes the new moment back to host — the round trip is value-level
+    identity, so offload on/off updates are equal
+    (tests/test_opt_offload.py).
 
     moments_dtype ("fp8" | "int8", DESIGN.md §14): the host residency is
     the compressed ``(payload, scale)`` pair — the H2D brings both on
@@ -140,18 +137,14 @@ def apply_update(params, grads, state: AdamWState, *, lr, b1=0.9, b2=0.95,
     probe: optional identity hook (runtime/memledger.update_probe) threaded
     onto the step counter — runtime evidence that the update phase executed.
     """
-    assert moments_mode in ("explicit", "xla"), moments_mode
     compressed = moments_dtype not in (None, "none")
-    assert not compressed or (offload_moments
-                              and moments_mode == "explicit"), (
-        "moments_dtype compression requires offload_moments with "
-        "moments_mode='explicit'")
-    gnorm = global_norm(grads)
+    assert not compressed or offload_moments, (
+        "moments_dtype compression requires offload_moments")
+    gnorm = global_norm(grads) if grad_norm is None else grad_norm
     scale = jnp.minimum(1.0, clip_norm / jnp.maximum(gnorm, 1e-12))
     step = state.step + 1
     bc1 = 1 - b1 ** step.astype(jnp.float32)
     bc2 = 1 - b2 ** step.astype(jnp.float32)
-    kind = hostmem.resolve_host_kind(host_kind) if offload_moments else None
 
     def upd(p, g, m, v):
         g = g.astype(jnp.float32) * scale
@@ -174,29 +167,33 @@ def apply_update(params, grads, state: AdamWState, *, lr, b1=0.9, b2=0.95,
         (payload, scale) pair and dequantize; raw: H2D the named leaf)."""
         if compressed:
             payload, sc = leaf
-            payload = hostmem.to_device(checkpoint_name(payload, name), kind)
-            sc = hostmem.to_device(checkpoint_name(sc, scale_name), kind)
+            payload = hostmem.to_device(checkpoint_name(payload, name))
+            sc = hostmem.to_device(checkpoint_name(sc, scale_name))
             return hostmem.dequantize(payload, sc, moments_dtype, jnp.float32)
         # the *host-resident* buffer carries the name, mirroring the
         # act_off contract: what the ledger counts is what lives off
         # device between steps
-        leaf = checkpoint_name(leaf, name)
-        if moments_mode == "explicit":
-            leaf = hostmem.to_device(leaf, kind)   # one H2D per moment leaf
-        return leaf
+        return hostmem.to_device(checkpoint_name(leaf, name))  # one H2D
 
     def store(leaf_new):
         """Device moment -> host residency (compressed: quantize and D2H
         the pair; raw: D2H the leaf)."""
         if compressed:
             payload, sc = hostmem.quantize(leaf_new, moments_dtype)
-            return (hostmem.to_host(payload, kind), hostmem.to_host(sc, kind))
-        if offload_moments and moments_mode == "explicit":
-            return hostmem.to_host(leaf_new, kind)  # one D2H writes back
+            return hostmem.to_host(payload), hostmem.to_host(sc)
+        if offload_moments:
+            return hostmem.to_host(leaf_new)        # one D2H writes back
         return leaf_new
 
     out = []
     for i, (p, g, m, v) in enumerate(zip(flat_p, flat_g, flat_m, flat_v)):
+        if offload_moments and out and isinstance(p, jax.core.Tracer):
+            # leaf i's H2D waits for leaf i-1's D2H: one moment pair on
+            # device at a time — without the fence XLA's scheduler starts
+            # every leaf's H2D up front and holds the new moments until
+            # late D2Hs, so the whole moment set lands on device at once
+            # (eager calls run leaf by leaf anyway)
+            m, v, _ = jax.lax.optimization_barrier((m, v, out[-1]))
         if offload_moments:
             nm, nv = moment_names(i)
             nms, nvs = moment_scale_names(i)

@@ -41,18 +41,10 @@ from repro.parallel import specs as SP
 from repro.parallel.ctx import Ctx
 from repro.parallel.plans import resolve_plan
 
-try:  # jax >= 0.8
-    from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except (ImportError, TypeError):  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 DECODE_BUDGET = 128  # extra decode slots beyond the shape's cache length
@@ -279,7 +271,6 @@ def prefetch_chunk(cell: Cell, ctx: Ctx, *, alpha: float, names: tuple,
     mdef = cell.mdef
     off_name, keep_name = names
     codec = cell.plan.offload_dtype
-    kind = hostmem.resolve_host_kind("auto")
     meta = ChunkMeta(q_pos=q_pos, cache_off=cache_off, kv_view=kv_view,
                      tag=None, names=names, q_start=q_start)
 
@@ -294,14 +285,14 @@ def prefetch_chunk(cell: Cell, ctx: Ctx, *, alpha: float, names: tuple,
         # carry the bytes).  Same byte count either way, so the ledger's
         # act_off accounting is unchanged by the transport view.
         off_host = tuple(
-            checkpoint_name(hostmem.to_host(hostmem.to_transport(t, codec),
-                                            kind), off_name)
+            checkpoint_name(hostmem.to_host(hostmem.to_transport(t, codec)),
+                            off_name)
             for t in off_acts)
         if mutation.active("double-d2h"):
-            off_host = tuple(hostmem.to_host(t, kind) for t in off_host)
+            off_host = tuple(hostmem.to_host(t) for t in off_host)
         keep_dev = tuple(checkpoint_name(t, keep_name) for t in keep_acts)
         if mutation.active("scale-offloaded"):
-            scales = tuple(hostmem.to_host(s, kind) for s in scales)
+            scales = tuple(hostmem.to_host(s) for s in scales)
         if mutation.active("unnamed-scale"):
             scale_dev = tuple(scales)
         else:
@@ -330,10 +321,12 @@ def prefetch_chunk(cell: Cell, ctx: Ctx, *, alpha: float, names: tuple,
         # cotangent to the previous chunk's seam.  Reloads stay in wire
         # form across the link — dequantization belongs to the chunk that
         # owns the scales (its own backward, below).
-        staged_prev = jax.tree_util.tree_map(
-            lambda t: hostmem.to_device(t, kind), link_in)
+        staged_prev = jax.tree_util.tree_map(hostmem.to_device, link_in)
         staged_off = tuple(hostmem.from_transport(t, codec)
                            for t in staged_off)
+        if mutation.active("scale-offloaded"):
+            # the hosted scales must come back before dequantize can use them
+            scale_dev = tuple(hostmem.to_device(s) for s in scale_dev)
 
         def replay(stage_p, g, state, x):
             return mdef.stage_apply_inject(
@@ -359,8 +352,6 @@ def link_drain(y, link):
         return y
     from repro.runtime import hostmem
 
-    kind = hostmem.resolve_host_kind("auto")
-
     @jax.custom_vjp
     def attach(y, link):
         return y
@@ -369,8 +360,7 @@ def link_drain(y, link):
         return y, link
 
     def attach_bwd(link_res, ct_y):
-        staged = jax.tree_util.tree_map(
-            lambda t: hostmem.to_device(t, kind), link_res)
+        staged = jax.tree_util.tree_map(hostmem.to_device, link_res)
         return ct_y, staged
 
     attach.defvjp(attach_fwd, attach_bwd)
@@ -650,37 +640,72 @@ def _in_specs_for_params(cell: Cell):
 # ---------------------------------------------------------------------------
 
 
-def make_train_step(cell: Cell, mesh, *, lr_kwargs=None, ledger=None):
+def _step_inputs(stage_p, batch):
+    """Per-device views of one step's inputs inside shard_map: the stage
+    params and (tokens, labels, context, doc_start) with their mesh-lead
+    axes squeezed (context / doc_start are None when the batch has none)."""
+    def opt(k):
+        return _squeeze_lead(batch[k], 2) if k in batch else None
+
+    return (_squeeze_lead(stage_p, 1), _squeeze_lead(batch["tokens"], 2),
+            _squeeze_lead(batch["labels"], 2), opt("context"),
+            opt("doc_start"))
+
+
+def _make_loss_fn(cell: Cell, ctx: Ctx, ledger=None):
+    """The scalar loss of one (micro)batch that train_step differentiates:
+    (stage_p, g, tokens, labels, context, doc_start) -> loss."""
+    def loss_fn(stage_p, g, tok, lab, ctxt, ds):
+        out = run_pipeline(cell, ctx, stage_p, g, tok, lab, ctxt,
+                           with_loss=True, ledger=ledger,
+                           doc_start=ds if cell.varlen else None)
+        den = jnp.maximum(ctx.psum_loss_all(out["denom"]), 1.0)
+        share = out["loss"] / den
+        if cell.cfg.moe is not None:
+            share = share + 0.01 * out["aux"] / (
+                cell.data_size * cell.pods * cell.plan.sp * cell.sched.n
+                * max(1, cell.mdef.n_slots))
+        return share, ctx.psum_loss_all(share)
+
+    return loss_fn
+
+
+def make_loss_step(cell: Cell, mesh):
+    """Forward-only ``loss_step(params, batch) -> loss``: train_step's loss
+    with no backward and no update (with grad_accum 1 the same number
+    train_step reports for that batch) — a reference that needs no
+    activation memory for the backward."""
+    pspecs = _in_specs_for_params(cell)
+    _, bspecs = batch_struct(cell)
+
+    def smap_body(stage_p, g, batch):
+        sp, tok, lab, ctxt, ds = _step_inputs(stage_p, batch)
+        return _make_loss_fn(cell, cell.ctx())(sp, g, tok, lab, ctxt, ds)[1]
+
+    smapped = shard_map(
+        smap_body, mesh,
+        in_specs=(pspecs["stages"], pspecs["globals"], bspecs),
+        out_specs=P())
+    return lambda params, batch: smapped(params["stages"], params["globals"],
+                                         batch)
+
+
+def make_grad_step(cell: Cell, mesh, *, ledger=None):
+    """``grad_step(params, batch) -> (loss, grads, grad_norm)``: the step's
+    mean loss, its gradient laid out like the params (each stage's entries
+    identical across its dp replicas) and the global norm of that gradient
+    counting every parameter once."""
     from repro.optim import adamw
 
     plan = cell.plan
     pspecs = _in_specs_for_params(cell)
-    bstruct, bspecs = batch_struct(cell)
-    lr_kwargs = lr_kwargs or {}
+    _, bspecs = batch_struct(cell)
 
     def smap_body(stage_p, g, batch):
         ctx = cell.ctx()
-        stage_p = _squeeze_lead(stage_p, 1)
-        tokens = _squeeze_lead(batch["tokens"], 2)
-        labels = _squeeze_lead(batch["labels"], 2)
-        context = (_squeeze_lead(batch["context"], 2)
-                   if "context" in batch else None)
-        doc_start = (_squeeze_lead(batch["doc_start"], 2)
-                     if "doc_start" in batch else None)
-
-        def loss_fn(stage_p, g, tok, lab, ctxt, ds):
-            out = run_pipeline(cell, ctx, stage_p, g, tok, lab, ctxt,
-                               with_loss=True, ledger=ledger,
-                               doc_start=ds if cell.varlen else None)
-            num = ctx.psum_loss_all(out["loss"])
-            den = ctx.psum_loss_all(out["denom"])
-            aux = ctx.psum_loss_all(out["aux"])
-            loss = num / jnp.maximum(den, 1.0)
-            if cell.cfg.moe is not None:
-                loss = loss + 0.01 * aux / (cell.data_size * cell.pods
-                                            * cell.plan.sp * cell.sched.n
-                                            * max(1, cell.mdef.n_slots))
-            return loss
+        stage_p, tokens, labels, context, doc_start = _step_inputs(stage_p,
+                                                                   batch)
+        loss_fn = _make_loss_fn(cell, ctx, ledger)
 
         A = plan.grad_accum
         if A > 1:
@@ -695,7 +720,8 @@ def make_train_step(cell: Cell, mesh, *, lr_kwargs=None, ledger=None):
             def acc_step(carry, xs):
                 gsum, lsum = carry
                 tok, lab, cx, ds = xs
-                l, gr = jax.value_and_grad(loss_fn, argnums=(0, 1))(
+                (_, l), gr = jax.value_and_grad(loss_fn, argnums=(0, 1),
+                                                has_aux=True)(
                     stage_p, g, tok, lab, cx, ds)
                 gsum = jax.tree_util.tree_map(
                     lambda a, b: a + b.astype(a.dtype), gsum, gr)
@@ -710,8 +736,16 @@ def make_train_step(cell: Cell, mesh, *, lr_kwargs=None, ledger=None):
             loss = loss / A
             grads = jax.tree_util.tree_map(lambda a: a / A, grads)
         else:
-            loss, grads = jax.value_and_grad(loss_fn, argnums=(0, 1))(
+            (_, loss), grads = jax.value_and_grad(
+                loss_fn, argnums=(0, 1), has_aux=True)(
                 stage_p, g, tokens, labels, context, doc_start)
+        # a param replicated over the model axis saw only this rank's
+        # sequence shard: sum the shards (model-sharded params were
+        # gathered for compute, so their grads came back reduce-scattered)
+        grads = jax.tree.map(
+            lambda gr, spec: gr if "model" in spec else ctx.psum_model(gr),
+            grads, (pspecs["stages"], pspecs["globals"]),
+            is_leaf=lambda x: isinstance(x, P))
         # stage grads reduce over dp replicas; global grads over all stages
         g_stage = ctx.psum_grads(grads[0])
         g_glob = ctx.psum_globals(grads[1])
@@ -723,15 +757,30 @@ def make_train_step(cell: Cell, mesh, *, lr_kwargs=None, ledger=None):
         in_specs=(pspecs["stages"], pspecs["globals"], bspecs),
         out_specs=(P(), pspecs["stages"], pspecs["globals"]))
 
-    def train_step(params, opt_state, batch):
+    def grad_step(params, batch):
         loss, gs, gg = smapped(params["stages"], params["globals"], batch)
-        grads = {"stages": gs, "globals": gg}
+        # the stacked stage grads hold each stage once per dp replica
+        gnorm = jnp.sqrt(jnp.square(adamw.global_norm(gs)) / plan.dp
+                         + jnp.square(adamw.global_norm(gg)))
+        return loss, {"stages": gs, "globals": gg}, gnorm
+
+    return grad_step
+
+
+def make_train_step(cell: Cell, mesh, *, lr_kwargs=None, ledger=None):
+    from repro.optim import adamw
+
+    plan = cell.plan
+    grad_step = make_grad_step(cell, mesh, ledger=ledger)
+    lr_kwargs = lr_kwargs or {}
+
+    def train_step(params, opt_state, batch):
+        loss, grads, gnorm = grad_step(params, batch)
         lr = adamw.cosine_lr(opt_state.step, **lr_kwargs)
         new_p, new_o, met = adamw.apply_update(
             params, grads, opt_state, lr=lr,
             offload_moments=plan.offload_moments,
-            moments_mode=plan.moments_mode,
-            moments_dtype=plan.moments_dtype)
+            moments_dtype=plan.moments_dtype, grad_norm=gnorm)
         met["loss"] = loss
         return new_p, new_o, met
 

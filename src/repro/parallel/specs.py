@@ -106,17 +106,16 @@ def shardings(mesh, specs, memory_kind: Optional[str] = None):
     return jax.tree_util.tree_map(mk, specs)
 
 
-def moment_shardings(mesh, opt_param_specs, *, offload_moments: bool = False,
-                     host_kind="auto"):
+def moment_shardings(mesh, opt_param_specs, *, offload_moments: bool = False):
     """NamedShardings for the AdamW moment trees (DESIGN.md §11): the
     param-mirroring specs from ``opt_specs``, committed to the backend's
     host memory kind when the plan offloads moments.  This is the sharding
-    side of the executed path — apply_update's explicit H2D/D2H copies (or
-    XLA's streaming, moments_mode="xla") are what move the bytes."""
+    side of the executed path — apply_update's explicit H2D/D2H copies are
+    what move the bytes."""
     kind = None
     if offload_moments:
         from repro.runtime import hostmem
-        kind = hostmem.resolve_host_kind(host_kind)
+        kind = hostmem.host_memory_kind(mesh.devices.flat[0])
     return shardings(mesh, opt_param_specs, memory_kind=kind)
 
 

@@ -1,16 +1,12 @@
-"""Shared host-memory-kind helpers (DESIGN.md §10/§11).
+"""Shared host-memory helpers (DESIGN.md §10/§11).
 
 Both executed-offload paths — activations (core/offload.py) and optimizer
-moments (optim/adamw.py) — place tensors into the best host memory space the
-backend exposes and move them back with explicit ``device_put`` dataflow:
-
-  * ``pinned_host``   on TPU/GPU (DMA-able, the paper's offload target);
-  * ``unpinned_host`` on CPU (XLA folds host into device, but the program
-    structure — and therefore the jaxpr accounting — is identical);
-  * ``None``          when the backend has no memory kinds at all, in which
-    case callers fall back to the barrier-fenced staged-copy emulation
-    (``optimization_barrier`` around the named save point) so the graph
-    keeps the same shape.
+moments (optim/adamw.py) — place tensors into the device's ``pinned_host``
+memory and move them back with explicit ``device_put`` dataflow.  Inside
+``jit`` the copies are ``jax.memory.Space.Host`` / ``Space.Device`` puts
+(the jaxpr shows ``f32<host>`` avals); concrete arrays are committed
+through their own sharding re-kinded with ``with_memory_kind``.  A device
+without ``pinned_host`` memory is an error, never a silent no-op.
 
 This module is the single home for the memory-kind probe and the D2H/H2D
 primitives; it imports nothing from ``repro`` so every layer (core, optim,
@@ -21,100 +17,42 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-
-try:  # public home moves across jax versions
-    from jax.sharding import TransferToMemoryKind
-except ImportError:  # pragma: no cover - version-dependent
-    try:
-        from jax._src.sharding_impls import TransferToMemoryKind
-    except ImportError:
-        TransferToMemoryKind = None
+from jax.sharding import NamedSharding, PartitionSpec
 
 DEVICE_KIND = "device"
-HOST_KIND_PREFERENCE = ("pinned_host", "unpinned_host")
-
-_HOST_KIND_CACHE: dict = {}
+HOST_KIND = "pinned_host"
 
 
-def host_memory_kind(backend: Optional[str] = None) -> Optional[str]:
-    """Best host memory kind the default device exposes: 'pinned_host'
-    (TPU/GPU) > 'unpinned_host' (CPU) > None (no memory-kind support —
-    the staged-copy emulation takes over)."""
-    key = backend or "default"
-    if key in _HOST_KIND_CACHE:
-        return _HOST_KIND_CACHE[key]
-    kind = None
-    if TransferToMemoryKind is not None:
-        try:
-            dev = jax.devices(backend)[0] if backend else jax.devices()[0]
-            kinds = {m.kind for m in dev.addressable_memories()}
-            for cand in HOST_KIND_PREFERENCE:
-                if cand in kinds:
-                    kind = cand
-                    break
-        except Exception:  # pragma: no cover - backend-dependent
-            kind = None
-    _HOST_KIND_CACHE[key] = kind
-    return kind
-
-
-def resolve_host_kind(host_kind="auto") -> Optional[str]:
-    """'auto' -> probe the backend; anything else passes through (a kind
-    string, or None to force the barrier-fenced emulation)."""
-    return host_memory_kind() if host_kind == "auto" else host_kind
+def host_memory_kind(device=None) -> str:
+    """The host memory kind offloads go to: ``pinned_host``, which TPU, GPU
+    and CPU devices all expose.  Raises when `device` (default: the first
+    device) has no such memory."""
+    dev = device if device is not None else jax.devices()[0]
+    kinds = sorted(m.kind for m in dev.addressable_memories())
+    if HOST_KIND not in kinds:
+        raise RuntimeError(f"{dev.device_kind} exposes no {HOST_KIND!r} "
+                           f"memory (has {kinds}); host offload needs it")
+    return HOST_KIND
 
 
 def _is_traced(t) -> bool:
     return isinstance(t, jax.core.Tracer)
 
 
-def _default_device_kind(t) -> str:
-    """The default (device) memory kind of `t`'s devices — 'device' on
-    TPU/GPU, 'unpinned_host' on CPU (host == device there)."""
-    try:
-        dev = next(iter(t.devices()))
-    except Exception:  # pragma: no cover - non-committed values
-        dev = jax.devices()[0]
-    return dev.default_memory().kind
-
-
-def to_host(t, kind: Optional[str]):
-    """One D2H: place `t` in host memory space (emulation: barrier fence,
-    so XLA must materialize the staged buffer instead of fusing it away).
-    Inside jit this is the ``TransferToMemoryKind`` device_put form the
-    ledger's copy accounting counts; eagerly it commits the concrete
-    array's own sharding into the host kind."""
-    if kind is None:
-        return jax.lax.optimization_barrier(t)
+def to_host(t):
+    """One D2H: place `t` in host memory.  Inside jit this is the
+    ``Space.Host`` device_put the ledger's copy accounting counts; eagerly
+    it commits the concrete array's own sharding into pinned_host."""
     if _is_traced(t):
-        return jax.device_put(t, TransferToMemoryKind(kind))
-    return jax.device_put(t, host_sharding_like(t, kind))
+        return jax.device_put(t, jax.memory.Space.Host)
+    return jax.device_put(t, t.sharding.with_memory_kind(host_memory_kind()))
 
 
-def to_device(t, kind: Optional[str]):
-    """One H2D: bring a host-resident `t` back to device memory space.
-    `kind` is the host kind the value lives in (None = emulation fence)."""
-    if kind is None:
-        return jax.lax.optimization_barrier(t)
+def to_device(t):
+    """One H2D: bring a host-resident `t` back to device memory."""
     if _is_traced(t):
-        return jax.device_put(t, TransferToMemoryKind(DEVICE_KIND))
-    return jax.device_put(t, host_sharding_like(t, _default_device_kind(t)))
-
-
-def host_sharding_like(arr, kind: str):
-    """A sharding placing `arr`'s layout into `kind` host memory: the
-    array's own sharding re-kinded when it carries one (NamedSharding /
-    SingleDeviceSharding both support with_memory_kind), else a
-    single-device host placement."""
-    sh = getattr(arr, "sharding", None)
-    if sh is not None and hasattr(sh, "with_memory_kind"):
-        try:
-            return sh.with_memory_kind(kind)
-        except Exception:  # pragma: no cover - exotic shardings
-            pass
-    from jax.sharding import SingleDeviceSharding
-
-    return SingleDeviceSharding(jax.devices()[0], memory_kind=kind)
+        return jax.device_put(t, jax.memory.Space.Device)
+    return jax.device_put(t, t.sharding.with_memory_kind(DEVICE_KIND))
 
 
 def row_scale_sharding(p, kind: str):
@@ -123,46 +61,33 @@ def row_scale_sharding(p, kind: str):
     trailing dim is 1 and cannot carry the payload's last-axis shards (a
     model-sharded (rows, d) param would ask the (rows, 1) scale to split
     its singleton axis)."""
-    sh = getattr(p, "sharding", None)
-    if sh is not None:
-        try:
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            if (isinstance(sh, NamedSharding) and p.ndim >= 1
-                    and len(sh.spec) == p.ndim and sh.spec[-1] is not None):
-                sh = NamedSharding(sh.mesh,
-                                   PartitionSpec(*sh.spec[:-1], None))
-            return sh.with_memory_kind(kind)
-        except Exception:  # pragma: no cover - exotic shardings
-            pass
-    from jax.sharding import SingleDeviceSharding
-
-    return SingleDeviceSharding(jax.devices()[0], memory_kind=kind)
+    sh = p.sharding
+    if (isinstance(sh, NamedSharding) and p.ndim >= 1
+            and len(sh.spec) == p.ndim and sh.spec[-1] is not None):
+        sh = NamedSharding(sh.mesh, PartitionSpec(*sh.spec[:-1], None))
+    return sh.with_memory_kind(kind)
 
 
-def host_zeros(shape, dtype, kind: Optional[str], like=None, sharding=None):
+def host_zeros(shape, dtype, like=None, sharding=None):
     """Zeros born in host memory: the buffer is built host-side (numpy) and
-    placed directly into the host memory space, so *no device allocation
-    ever happens* — the init_state fix for the step-0 peak spike
-    (DESIGN.md §11).  With no memory kinds the plain device zeros are the
-    only option (host == device there anyway).  Under abstract tracing
-    (eval_shape / jit of init — the dry-run's shape-only path) a concrete
-    host buffer must not materialize, so this falls back to traced zeros;
-    the real init paths (launch/train.py, memledger) are eager."""
+    placed directly into pinned_host, so *no device allocation ever
+    happens* — the init_state fix for the step-0 peak spike (DESIGN.md
+    §11).  Under abstract tracing (eval_shape / jit of init — the dry-run's
+    shape-only path) a concrete host buffer must not materialize, so this
+    emits traced zeros immediately put to host; the real init paths
+    (launch/train.py, memledger) are eager."""
     import numpy as np
 
     import jax.numpy as jnp
 
-    if kind is None:
-        return jnp.zeros(shape, dtype)
     if _is_traced(like):
-        # traced zeros, immediately host-placed — the jaxpr keeps the
-        # host-residency fact (memledger.init_moment_device_bytes nets
-        # host-placed creations out of the device-space count)
-        return to_host(jnp.zeros(shape, dtype), kind)
+        # the jaxpr keeps the host-residency fact
+        # (memledger.init_moment_device_bytes nets host-placed creations
+        # out of the device-space count)
+        return to_host(jnp.zeros(shape, dtype))
     host = np.zeros(shape, np.dtype(dtype))
     if sharding is None:
-        sharding = host_sharding_like(like, kind)
+        sharding = like.sharding.with_memory_kind(host_memory_kind())
     return jax.device_put(host, sharding)
 
 

@@ -6,7 +6,7 @@ Measurement channels, all taken from the *real* program:
 1. **Tagged-byte accounting** — every pipeline tick tags its Type-1
    activations with tick-qualified checkpoint names (``act_off@t3`` /
    ``act_keep@t3``, runner.chunk_tag).  ``tagged_bytes_from_jaxpr`` walks
-   the traced jaxpr of the loss (through pjit / shard_map / remat / scan,
+   the traced jaxpr of the loss (through jit / shard_map / remat / scan,
    multiplying by scan trip counts) and sums the exact aval bytes behind
    each name.  Shapes are static facts of the executed program, so this is
    exact per-device accounting — not an estimate.
@@ -80,10 +80,7 @@ import jax.numpy as jnp
 
 from repro.core import offload as ofl
 
-try:  # jax >= 0.4.27
-    from jax.experimental import io_callback
-except ImportError:  # pragma: no cover - very old jax
-    io_callback = None
+from jax.experimental import io_callback
 
 
 # ---------------------------------------------------------------------------
@@ -202,7 +199,6 @@ def device_put_kinds(closed_jaxpr) -> Dict[str, int]:
 
 
 def init_moment_device_bytes(params, opt_dtype, *, offload_moments: bool,
-                             host_kind="auto",
                              moments_dtype: str = "none") -> int:
     """Bytes of moment zeros that end up resident in *device* memory space
     after ``adamw.init_state``, from the traced init: creation equations
@@ -217,7 +213,7 @@ def init_moment_device_bytes(params, opt_dtype, *, offload_moments: bool,
 
     cjx = jax.make_jaxpr(lambda ps: adamw.init_state(
         ps, opt_dtype, offload_moments=offload_moments,
-        host_kind=host_kind, moments_dtype=moments_dtype))(params)
+        moments_dtype=moments_dtype))(params)
     created: Dict[object, int] = {}
     dev = 0
     for eqn in cjx.jaxpr.eqns:
@@ -227,10 +223,8 @@ def init_moment_device_bytes(params, opt_dtype, *, offload_moments: bool,
             for v in eqn.outvars:
                 created[v] = _aval_bytes(v.aval)
         elif eqn.primitive.name == "device_put":
-            kinds = [getattr(d, "memory_kind", None)
-                     for d in eqn.params.get("devices", ())]
-            if kinds and all(k not in (None, hostmem.DEVICE_KIND)
-                             for k in kinds):
+            kinds = _df.device_put_kinds_of(eqn)
+            if kinds and all(k != hostmem.DEVICE_KIND for k in kinds):
                 for v in eqn.invars:
                     dev -= created.pop(v, 0)
     return dev
@@ -241,7 +235,6 @@ class MomentChannel:
     """Measured optimizer-state residency for one cell's update step."""
 
     offloaded: bool
-    mode: str                      # moments_mode: explicit | xla
     opt_dtype: str
     host_kind: Optional[str]
     m_bytes: int                   # real state buffers (Σ leaf nbytes)
@@ -630,11 +623,11 @@ def step_fn(cell, *, data_size: int, model_size: int, ledger=None,
     ``jax.make_jaxpr`` it over ShapeDtypeStructs without allocating."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel.runner import (_in_specs_for_params, batch_struct,
                                        run_pipeline, shard_map)
 
-    mesh = compat_make_mesh((data_size, model_size), ("data", "model"))
+    mesh = make_mesh((data_size, model_size), ("data", "model"))
     pspecs = _in_specs_for_params(cell)
     _, bspecs = batch_struct(cell)
 
@@ -834,7 +827,7 @@ def _measure_opt(cell, ledger: MemLedger, params, grads) -> None:
     def opt_fn(p, g, s):
         return adamw.apply_update(
             p, g, s, lr=1e-3, offload_moments=plan.offload_moments,
-            moments_mode=plan.moments_mode, probe=probe,
+            probe=probe,
             moments_dtype=moments_dtype)
 
     cjx = jax.make_jaxpr(opt_fn)(params, grads, state)
@@ -858,7 +851,6 @@ def _measure_opt(cell, ledger: MemLedger, params, grads) -> None:
 
     ledger.moments = MomentChannel(
         offloaded=plan.offload_moments,
-        mode=plan.moments_mode,
         opt_dtype=plan.opt_dtype,
         host_kind=kind,
         m_bytes=sum(int(m.nbytes) for m in leaves_m),
@@ -881,7 +873,7 @@ def measure(cell, *, data_size: int, model_size: int, seed: int = 0,
     and (optionally) time an offload-off baseline for the exposed-transfer
     estimate.  With ``opt`` the optimizer update is measured too (the
     moments channel, §11): one real AdamW step over the measured grads
-    with the plan's ``offload_moments``/``moments_mode``.  ``d2h_bw``
+    with the plan's ``offload_moments``.  ``d2h_bw``
     prices the exposed-H2D channel (§12); pass the bandwidth of the
     hardware profile the cell was resolved against when it is not the
     default V5E.  Requires grad_accum == 1 (the jaxpr scan walk would

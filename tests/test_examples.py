@@ -33,7 +33,7 @@ def test_long_context_training_fast(eight_devices):
     assert all(math.isfinite(l) for l in losses)
 
 
-@pytest.mark.skipif(os.environ.get("REPRO_USE_PALLAS") == "1",
+@pytest.mark.skipif(os.environ.get("REPRO_ATTENTION") == "interpret",
                     reason="quickstart is covered by the jnp leg")
 def test_examples_are_argv_driven():
     """Both examples accept argv lists (the CI smoke contract)."""

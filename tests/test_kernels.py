@@ -1,6 +1,6 @@
 """Pallas kernel vs pure-jnp oracle: shape/dtype sweeps + merge properties,
 plus the end-to-end training contract: a full train step (loss + grads)
-under ``REPRO_USE_PALLAS=1`` interpret mode must match the jnp backend
+on the Pallas kernels in interpret mode must match the jnp backend
 per-parameter — single-device and through the pp>1 tick loop."""
 import dataclasses
 
@@ -95,7 +95,7 @@ def test_empty_kv_rows_are_zero():
 
 
 # ---------------------------------------------------------------------------
-# End-to-end training contract: REPRO_USE_PALLAS=1 == jnp backend, grads too
+# End-to-end training contract: Pallas (interpret) == jnp backend, grads too
 # ---------------------------------------------------------------------------
 
 
@@ -136,12 +136,12 @@ def _dist_loss_grads(mdef, tokens, labels, *, pp=2, mesh_shape=(2, 2),
     """The pp>1 tick loop, grads computed exactly as make_train_step does:
     value_and_grad inside shard_map, stage/global psums."""
     from repro.configs.base import ShapeConfig
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel.runner import (_in_specs_for_params, batch_struct,
                                        resolve_cell, run_pipeline, shard_map)
 
     data_size, model_size = mesh_shape
-    mesh = compat_make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     dp = data_size // pp
     B, S = tokens.shape
     overrides = dict(n_chunks=2, grad_accum=1, pp=pp, dp=dp,
@@ -211,7 +211,7 @@ def test_train_step_grads_pallas_equals_jnp_single(kernel_backend):
     labels = jnp.roll(tokens, -1, axis=1)
     with kernel_backend("jnp"):
         loss_j, grads_j = _single_loss_grads(mdef, tokens, labels)
-    with kernel_backend("pallas"):
+    with kernel_backend("interpret"):
         loss_p, grads_p = _single_loss_grads(mdef, tokens, labels)
     assert abs(loss_p - loss_j) <= 1e-4
     assert _max_abs_diff(grads_p, grads_j) <= 1e-4
@@ -227,7 +227,7 @@ def test_train_py_runs_on_pallas_backend(kernel_backend):
             "--seq", "64", "--batch", "2", "--mesh", "1x1"]
     with kernel_backend("jnp"):
         hist_j = main(args)
-    with kernel_backend("pallas"):
+    with kernel_backend("interpret"):
         hist_p = main(args)
     assert np.isfinite(hist_p[-1]["loss"])
     np.testing.assert_allclose(hist_p[0]["loss"], hist_j[0]["loss"],
@@ -243,7 +243,7 @@ def test_train_step_grads_pallas_equals_jnp_pp2(kernel_backend, eight_devices):
     labels = jnp.roll(tokens, -1, axis=1)
     with kernel_backend("jnp"):
         loss_j, grads_j = _dist_loss_grads(mdef, tokens, labels)
-    with kernel_backend("pallas"):
+    with kernel_backend("interpret"):
         loss_p, grads_p = _dist_loss_grads(mdef, tokens, labels)
     assert abs(loss_p - loss_j) <= 1e-4
     assert _max_abs_diff(grads_p, grads_j) <= 1e-4
@@ -261,7 +261,7 @@ def test_train_step_grads_pallas_equals_jnp_gather_kv(kernel_backend, eight_devi
     with kernel_backend("jnp"):
         loss_j, grads_j = _dist_loss_grads(mdef, tokens, labels,
                                            extra_overrides=ov)
-    with kernel_backend("pallas"):
+    with kernel_backend("interpret"):
         loss_p, grads_p = _dist_loss_grads(mdef, tokens, labels,
                                            extra_overrides=ov)
     assert abs(loss_p - loss_j) <= 1e-4

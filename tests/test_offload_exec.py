@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ShapeConfig, get_config
-from repro.core import offload as ofl
 from repro.models.model_zoo import build_model
 from repro.parallel.ctx import SINGLE
 from repro.parallel.runner import resolve_cell, run_pipeline
@@ -120,10 +119,7 @@ def test_pp1_offload_on_off_loss_and_grads_match():
 def test_exec_path_emits_host_memory_transfers(eight_devices):
     """The differentiated pp>1 program contains memory-kind device_puts
     into a host space for every offloading tick, and none with offload
-    disabled.  (On backends without memory kinds the staged-copy emulation
-    has no such markers — skip there.)"""
-    if ofl.host_memory_kind() is None:
-        pytest.skip("backend has no host memory kind (emulation path)")
+    disabled."""
     cfg = get_config("qwen2-7b").reduced()
     mdef = build_model(cfg)
     tokens, labels = _tokens(cfg)
@@ -132,9 +128,7 @@ def test_exec_path_emits_host_memory_transfers(eight_devices):
         cell = _mk_cell(mdef, pp=2, offload=offload)
         fn, args = ml.build_step(cell, data_size=4, model_size=2,
                                  tokens=tokens, labels=labels)
-        txt = str(jax.make_jaxpr(fn)(*args))
-        kind = ofl.host_memory_kind()
-        return txt.count(kind) + txt.count("<host>")
+        return str(jax.make_jaxpr(fn)(*args)).count("<host>")
 
     assert markers(True) >= 10
     assert markers(False) == 0
@@ -254,8 +248,7 @@ def test_decode_plans_reject_compressed_residency():
     with pytest.raises(AssertionError, match="compressed residency"):
         resolve_cell(mdef, shape, data_size=4, model_size=2,
                      overrides=dict(moments_dtype="int8",
-                                    offload_moments=True,
-                                    moments_mode="explicit"))
+                                    offload_moments=True))
     # without its prerequisites the moments codec fails plan validation
     with pytest.raises(AssertionError, match="moments_dtype"):
         resolve_cell(mdef, shape, data_size=4, model_size=2,

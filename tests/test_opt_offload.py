@@ -77,24 +77,6 @@ def test_offload_identity_after_three_steps(opt_dtype, pp, clip_active):
                                    rtol=0, atol=1e-6)
 
 
-def test_xla_mode_matches_explicit():
-    """moments_mode='xla' (host-committed shardings, XLA streaming) and
-    'explicit' (one H2D/D2H per leaf) compute identical updates."""
-    params = _params(1)
-    grads = _grads(params, 1.0)
-    outs = []
-    for mode in ("explicit", "xla"):
-        p, s = params, adamw.init_state(params, jnp.float32,
-                                        offload_moments=True)
-        p, s, _ = adamw.apply_update(p, grads, s, lr=1e-3,
-                                     offload_moments=True, moments_mode=mode)
-        outs.append((p, s.m, s.v))
-    for a, b in zip(jax.tree_util.tree_leaves(outs[0]),
-                    jax.tree_util.tree_leaves(outs[1])):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=0, atol=1e-6)
-
-
 # ---------------------------------------------------------------------------
 # (b) the explicit path's jaxpr: host markers + one H2D per moment leaf
 # ---------------------------------------------------------------------------
@@ -114,10 +96,8 @@ def test_explicit_update_jaxpr_contract():
     # exactly one H2D per moment leaf per step (m and v trees each)
     assert kinds.get(hostmem.DEVICE_KIND, 0) == 2 * n_leaves, kinds
     # ... and one D2H writes each new moment back to host
-    host_kind = hostmem.host_memory_kind()
-    if host_kind is not None:
-        assert kinds.get(host_kind, 0) == 2 * n_leaves, kinds
-        assert str(cjx).count(host_kind) >= 2 * n_leaves
+    assert kinds.get(hostmem.host_memory_kind(), 0) == 2 * n_leaves, kinds
+    assert str(cjx).count("MemorySpace.Host") >= 2 * n_leaves
     # every moment leaf carries its ledger name
     named = ml.moment_bytes_from_jaxpr(cjx)
     assert len(named["leaves"]) == 2 * n_leaves
@@ -170,7 +150,7 @@ def test_runtime_coverage_requires_update_probe():
     update-phase probe fired — fwd/bwd tick evidence alone is not enough."""
     led = ml.MemLedger()
     led.moments = ml.MomentChannel(
-        offloaded=True, mode="explicit", opt_dtype="float32",
+        offloaded=True, opt_dtype="float32",
         host_kind=hostmem.host_memory_kind(), m_bytes=8, v_bytes=8,
         n_leaves=1, max_pair_bytes=16, named_bytes=16, h2d_count=2,
         d2h_count=2, init_dev_bytes=0)
@@ -188,7 +168,7 @@ def test_csv_roundtrip_moments_column(tmp_path):
                      "@c1": {"off": 0, "keep": 128}},
                     [(0, 0, 1), (1, 0, 1)], 1, (0.5, 0.0))
     led.moments = ml.MomentChannel(
-        offloaded=False, mode="explicit", opt_dtype="float32",
+        offloaded=False, opt_dtype="float32",
         host_kind=None, m_bytes=300, v_bytes=300, n_leaves=3,
         max_pair_bytes=200, named_bytes=0, h2d_count=0, d2h_count=0,
         init_dev_bytes=600)
@@ -226,10 +206,9 @@ def test_init_state_no_device_spike_regression():
         params, jnp.float32, offload_moments=False) == total
     # the concrete arrays really live in the host space
     kind = hostmem.host_memory_kind()
-    if kind is not None:
-        state = adamw.init_state(params, jnp.float32, offload_moments=True)
-        for leaf in jax.tree_util.tree_leaves((state.m, state.v)):
-            assert hostmem.memory_kind_of(leaf) == kind
+    state = adamw.init_state(params, jnp.float32, offload_moments=True)
+    for leaf in jax.tree_util.tree_leaves((state.m, state.v)):
+        assert hostmem.memory_kind_of(leaf) == kind
     # ledger arithmetic: steady-state device contribution is the staging
     # pair; step 0 adds init_dev_bytes on top — offloaded init adds nothing
     act_peak = 1000

@@ -10,7 +10,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ShapeConfig, get_config
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.model_zoo import build_model
 from repro.parallel.ctx import SINGLE
 from repro.parallel.runner import (_in_specs_for_params, batch_struct,
@@ -38,7 +38,7 @@ def _single_loss(mdef, cfg, tokens, labels, context):
 def _dist_loss(mdef, cfg, tokens, labels, context, *, pp, mesh_shape=(4, 2),
                extra_overrides=None):
     data_size, model_size = mesh_shape
-    mesh = compat_make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     dp = data_size // pp
     B, S = tokens.shape
     shape = ShapeConfig("t", S, B, "train")
@@ -166,3 +166,86 @@ def test_distributed_equals_single(arch, pp, eight_devices):
     ref = _single_loss(mdef, cfg, tokens, labels, context)
     got = _dist_loss(mdef, cfg, tokens, labels, context, pp=pp)
     np.testing.assert_allclose(got, ref, rtol=3e-4, atol=3e-4)
+
+
+def _step_grads(mdef, tokens, labels, mesh_shape, pp):
+    """(loss, grad_norm, per-slot stage grads, global grads) of the real
+    train-step gradient (runner.make_grad_step) on an fp32 cell — each
+    stage's grads taken once, whatever the dp replication."""
+    from jax.sharding import NamedSharding
+
+    from repro.data.pipeline import shard_batch
+    from repro.launch.mesh import make_test_mesh
+    from repro.launch.train import build_params
+    from repro.parallel.runner import make_grad_step
+
+    data_size, model_size = mesh_shape
+    B, S = tokens.shape
+    overrides = dict(grad_accum=1)
+    if pp:
+        overrides.update(pp=pp, dp=data_size // pp)
+    cell = resolve_cell(mdef, ShapeConfig("t", S, B, "train"),
+                        data_size=data_size, model_size=model_size,
+                        overrides=overrides)
+    cell = dataclasses.replace(cell, dtype=jnp.float32)
+    mesh = make_test_mesh(data_size, model_size)
+    params, _, _ = build_params(cell, mesh)
+    batch = shard_batch(tokens, labels, pods=1, data_size=data_size,
+                        pp=cell.plan.pp)
+    _, bspecs = batch_struct(cell)
+    # transfer-lint: ok (test fixture, batch placement onto the mesh)
+    batch = {k: jax.device_put(v, NamedSharding(mesh, bspecs[k]))
+             for k, v in batch.items()}
+    loss, grads, gnorm = jax.jit(make_grad_step(cell, mesh))(params, batch)
+    n = cell.plan.pp
+    stages = jax.tree_util.tree_map(
+        lambda a: np.asarray(a[:n]).reshape((-1,) + a.shape[2:]),
+        grads["stages"])
+    return (float(loss), float(gnorm), stages,
+            jax.tree_util.tree_map(np.asarray, grads["globals"]))
+
+
+@pytest.mark.parametrize("mesh_shape,pp,rtol", [
+    ((2, 1), 2, 1e-5),
+    # the sequence-sharded (sp = 2) backward sits up to 0.4% per leaf from
+    # the single-device one in fp32 (ROADMAP D8, cause open); the bound
+    # still catches a dropped sequence shard (50%) or a psum-scaled
+    # gradient (2x), the faults this test was written for
+    ((1, 2), None, 1e-2),
+    ((2, 2), 2, 1e-2),
+], ids=["pp2", "sp2", "pp2xsp2"])
+def test_train_step_grads_match_single_device(mesh_shape, pp, rtol,
+                                              eight_devices):
+    """The train step's gradient and its clip norm do not depend on the
+    mesh: not scaled by the device count (the transpose of the loss psum
+    under shard_map), every sequence shard's contribution summed into the
+    params replicated over the model axis."""
+    cfg = get_config("sppo-gpt-7b").reduced()
+    mdef = build_model(cfg)
+    rng = np.random.default_rng(3)
+    tokens = rng.integers(0, cfg.vocab_size, (1, 128)).astype(np.int32)
+    labels = np.roll(tokens, -1, axis=1)
+    loss1, norm1, st1, gl1 = _step_grads(mdef, tokens, labels, (1, 1), None)
+    loss, norm, st, gl = _step_grads(mdef, tokens, labels, mesh_shape, pp)
+    np.testing.assert_allclose(loss, loss1, rtol=1e-6)
+    np.testing.assert_allclose(norm, norm1, rtol=rtol)
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path((st, gl)),
+            jax.tree_util.tree_leaves((st1, gl1))):
+        err = np.linalg.norm(a - b)
+        assert err <= rtol * np.linalg.norm(b), (jax.tree_util.keystr(path),
+                                                 err, np.linalg.norm(b))
+
+
+def test_grad_norm_counts_dp_replicas_once(eight_devices):
+    """At dp = 2 the stacked stage grads hold every stage twice; the clip
+    norm counts each parameter once."""
+    cfg = get_config("sppo-gpt-7b").reduced()
+    mdef = build_model(cfg)
+    rng = np.random.default_rng(3)
+    tokens = rng.integers(0, cfg.vocab_size, (2, 128)).astype(np.int32)
+    _, norm, st, gl = _step_grads(mdef, tokens, np.roll(tokens, -1, axis=1),
+                                  (2, 1), None)
+    once = np.sqrt(sum(np.sum(np.square(a, dtype=np.float64))
+                       for a in jax.tree_util.tree_leaves((st, gl))))
+    np.testing.assert_allclose(norm, once, rtol=1e-5)
