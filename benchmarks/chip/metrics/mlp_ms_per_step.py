@@ -1,0 +1,8 @@
+"""Device time per step, in milliseconds, of the operations whose innermost
+scope is ``sppo.mlp``: the dense (or expert) MLP, forward, recomputed and
+backward."""
+from .. import scopes
+
+
+def read(r):
+    return scopes.ms_per_step(r, scopes.in_layer(r, scopes.MLP))

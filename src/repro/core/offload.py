@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from repro.core import mutation
-from repro.runtime import hostmem
+from repro.runtime import hostmem, scopes
 
 OFF_NAME = "act_off"
 KEEP_NAME = "act_keep"
@@ -188,6 +188,7 @@ def make_tag(alpha: float, *, axis: int = 1,
     alpha = float(alpha)
     off_name, keep_name = names
 
+    @scopes.scoped(scopes.OFFLOAD)
     def tag(t):
         if alpha <= 0.0:
             return checkpoint_name(t, keep_name)
@@ -291,6 +292,7 @@ def make_exec_tag(alpha: float, *, axis: int = 1,
     alpha = float(alpha)
     off_name, keep_name = names
 
+    @scopes.scoped(scopes.OFFLOAD)
     def tag(t):
         if alpha <= 0.0:
             return checkpoint_name(t, keep_name)
@@ -371,6 +373,7 @@ def make_capture_tag(alpha: float, collector: list, *, axis: int = 1,
         collector.append(("off", payload))
         collector.append(("scale", scale))
 
+    @scopes.scoped(scopes.OFFLOAD)
     def tag(t):
         rows = t.shape[axis]
         k = split_rows(rows, alpha)
@@ -412,6 +415,7 @@ def make_inject_tag(alpha: float, off_acts, keep_acts, *, axis: int = 1,
             return staged
         return hostmem.dequantize(staged, next(scale_it), codec, t_part.dtype)
 
+    @scopes.scoped(scopes.OFFLOAD)
     def tag(t):
         rows = t.shape[axis]
         k = split_rows(rows, alpha)

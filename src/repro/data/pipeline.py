@@ -13,9 +13,11 @@ duplicated across the pp stages of each dp group (stage-major layout).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Iterator, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import partition as part
@@ -231,6 +233,7 @@ def packed_batch_for(doc_lens: Sequence[int], seq_len: int, *, rows: int,
     return pack_documents(docs, seq_len, rows=rows)
 
 
+@functools.partial(jax.profiler.annotate_function, name="sppo.shard_batch")
 def shard_batch(tokens: np.ndarray, labels: np.ndarray, *, pods: int,
                 data_size: int, pp: int,
                 doc_start: Optional[np.ndarray] = None) -> dict:

@@ -9,7 +9,11 @@ and a full config on a real TPU mesh — only the mesh and config change.
 
 Features exercised: SPPO chunked pipeline with adaptive offload, AdamW with
 ZeRO-1/bf16 knobs, async sharded checkpointing + auto-resume, straggler
-watchdog, TGS/MFU metering.
+watchdog, TGS/MFU metering.  ``--trace-dir DIR`` writes a profiler trace of
+``--trace-steps`` steps (after step 0): device operations named by the
+program's layer scopes (runtime/scopes.py) and host spans ``sppo.step``
+with ``sppo.batch``, ``sppo.dispatch``, ``sppo.block`` and ``sppo.ckpt``
+inside it.
 """
 from __future__ import annotations
 
@@ -35,7 +39,8 @@ from repro.parallel.runner import (batch_struct, make_loss_step,
                                    make_train_step, resolve_cell)
 from repro.runtime import hostmem
 from repro.runtime.fault_tolerance import RestartSupervisor, StepWatchdog
-from repro.runtime.metrics import Meter, compile_stats, peak_flops
+from repro.runtime.metrics import (Meter, attention_flops, compile_stats,
+                                   peak_flops)
 
 log = logging.getLogger("repro.train")
 
@@ -141,6 +146,12 @@ def main(argv=None, *, report=None, loss_only=False):
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default=None)
+    ap.add_argument("--trace-dir", "--trace_dir", dest="trace_dir",
+                    default=None,
+                    help="write a jax.profiler trace of --trace-steps "
+                         "steps, from the step after the first, to DIR")
+    ap.add_argument("--trace-steps", "--trace_steps", dest="trace_steps",
+                    type=int, default=2)
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")
@@ -261,11 +272,13 @@ def main(argv=None, *, report=None, loss_only=False):
         data.load_state_dict(extra.get("data", data.state_dict()))
         log.info("resumed from step %d", start)
 
-    n_active = SP.count_active_params(mdef, cell.plan.pp, data_size)
     meter = Meter(n_chips=data_size * model_size,
                   tokens_per_step=args.batch * args.seq,
-                  n_active_params=n_active,
-                  peak_flops=peak_flops(mesh.devices.flat[0]))
+                  n_active_params=SP.count_matmul_params(mdef, cell.plan.pp,
+                                                         data_size),
+                  peak_flops=peak_flops(mesh.devices.flat[0]),
+                  attn_flops_per_step=attention_flops(
+                      cfg, batch=args.batch, seq=args.seq))
     watchdog = StepWatchdog()
 
     # compile up front (jit dispatch reuses this executable): set-up time,
@@ -292,27 +305,46 @@ def main(argv=None, *, report=None, loss_only=False):
              report["compiles"], report["cache_hits"],
              report["pallas_kernels"])
 
+    # --trace-dir: a profiler trace of --trace-steps steps from the step
+    # after the first (the first may still load or compile)
+    trace_from = start + 1
+    trace_to = min(trace_from + args.trace_steps, args.steps)
+
     def loop(resume_step: int):
         nonlocal params, opt_state
         data.state.step = resume_step
         for step in range(resume_step, args.steps):
-            batch = make_batch(step)
-            meter.start()
-            params, opt_state, metrics = step_fn(params, opt_state, batch)
-            jax.block_until_ready((params, opt_state))
-            loss = float(metrics["loss"])
-            rec = meter.stop(step, loss)
-            watchdog.observe(step, rec["dt"])
-            if step % args.log_every == 0 or step == args.steps - 1:
-                mfu = "n/a" if rec["mfu"] is None else f"{rec['mfu']:.3f}"
-                log.info("step %4d  loss %.4f  %.3fs  tgs %.1f  mfu %s  "
-                         "gnorm %.3f  compiles %d", step, loss, rec["dt"],
-                         rec["tgs"], mfu, float(metrics["grad_norm"]),
-                         rec["compiles"])
-            if ckpt and ((step + 1) % args.ckpt_every == 0
-                         or step == args.steps - 1):
-                ckpt.save(step + 1, (params, opt_state),
-                          extra={"data": data.state_dict()})
+            if args.trace_dir and step == trace_from < trace_to:
+                jax.profiler.start_trace(args.trace_dir)
+            with jax.profiler.StepTraceAnnotation("sppo.step",
+                                                  step_num=step):
+                with jax.profiler.TraceAnnotation("sppo.batch"):
+                    batch = make_batch(step)
+                meter.start()
+                with jax.profiler.TraceAnnotation("sppo.dispatch"):
+                    params, opt_state, metrics = step_fn(params, opt_state,
+                                                         batch)
+                with jax.profiler.TraceAnnotation("sppo.block"):
+                    jax.block_until_ready((params, opt_state))
+                    loss = float(metrics["loss"])
+                rec = meter.stop(step, loss)
+                watchdog.observe(step, rec["dt"])
+                if step % args.log_every == 0 or step == args.steps - 1:
+                    mfu = ("n/a" if rec["mfu"] is None
+                           else f"{rec['mfu']:.3f}")
+                    log.info("step %4d  loss %.4f  %.3fs  tgs %.1f  mfu %s  "
+                             "gnorm %.3f  compiles %d", step, loss, rec["dt"],
+                             rec["tgs"], mfu, float(metrics["grad_norm"]),
+                             rec["compiles"])
+                if ckpt and ((step + 1) % args.ckpt_every == 0
+                             or step == args.steps - 1):
+                    with jax.profiler.TraceAnnotation("sppo.ckpt"):
+                        ckpt.save(step + 1, (params, opt_state),
+                                  extra={"data": data.state_dict()})
+            if args.trace_dir and step + 1 == trace_to > trace_from:
+                jax.profiler.stop_trace()
+                log.info("trace of steps %d-%d written to %s", trace_from,
+                         trace_to - 1, args.trace_dir)
         if ckpt:
             ckpt.wait()
 

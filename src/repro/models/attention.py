@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.parallel.ctx import Ctx
+from repro.runtime import scopes
 
 PAD = jnp.int32(2**30)
 
@@ -151,6 +152,7 @@ def dist_attention(q, k_loc, v_loc, q_pos, kv_pos, ctx: Ctx, *, causal=True,
 # ---------------------------------------------------------------------------
 
 
+@scopes.scoped(scopes.ATTN)
 def gqa_self_attention(x, p, cfg, ctx: Ctx, cache: KVCache, q_pos,
                        cache_offset, kv_view, *, name_tag=None,
                        q_start=None):
@@ -186,6 +188,7 @@ def gqa_self_attention(x, p, cfg, ctx: Ctx, cache: KVCache, q_pos,
     return y, cache
 
 
+@scopes.scoped(scopes.ATTN)
 def gqa_decode_attention(x, p, cfg, ctx: Ctx, cache: KVCache, step_pos,
                          my_slot):
     """Single-token decode. x: [B_loc, 1, d]; step_pos: [] int32 global pos.
@@ -265,6 +268,7 @@ class PagedMeta(NamedTuple):
     block_tokens: int   # logical slots per block (static)
 
 
+@scopes.scoped(scopes.ATTN)
 def gqa_paged_decode_attention(x, p, cfg, ctx: Ctx, pool: PooledKV,
                                pg: PagedMeta):
     """Single-token decode against the paged pool. x: [B, 1, d].
@@ -329,6 +333,7 @@ def gqa_paged_decode_attention(x, p, cfg, ctx: Ctx, pool: PooledKV,
 # ---------------------------------------------------------------------------
 
 
+@scopes.scoped(scopes.ATTN)
 def mla_attention(x, p, cfg, ctx: Ctx, cache: KVCache, q_pos, cache_offset,
                   kv_view, *, name_tag=None, decode=False, my_slot=None,
                   q_start=None):
@@ -408,6 +413,7 @@ def mla_attention(x, p, cfg, ctx: Ctx, cache: KVCache, q_pos, cache_offset,
 # ---------------------------------------------------------------------------
 
 
+@scopes.scoped(scopes.ATTN)
 def cross_attention(x, p, cfg, ctx: Ctx, xkv, *, name_tag=None):
     """x: [B, T_loc, d]; xkv: precomputed context KV
     (k [B, Nctx_loc, Hkv, hd], v ..., pos [Nctx_loc]) sharded over `model`.
@@ -426,6 +432,7 @@ def cross_attention(x, p, cfg, ctx: Ctx, xkv, *, name_tag=None):
     return out @ p["wo"]
 
 
+@scopes.scoped(scopes.ATTN)
 def make_cross_kv(context, p, cfg, ctx: Ctx, n_valid: int):
     """context: [B, Nctx_loc, d] sequence-sharded stub embeddings.
     n_valid: global count of real (non-padded) context tokens."""

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel.ctx import Ctx
+from repro.runtime import scopes
 
 # ---------------------------------------------------------------------------
 # Norms
@@ -40,6 +41,7 @@ def layer_norm(x, scale, bias, eps: float = 1e-5):
     return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dt)
 
 
+@scopes.scoped(scopes.NORM)
 def apply_norm(x, p, kind: str):
     if kind == "rmsnorm":
         return rms_norm(x, p["scale"])
@@ -93,6 +95,7 @@ def _act(h, kind: str):
     raise ValueError(kind)
 
 
+@scopes.scoped(scopes.MLP)
 def mlp(x, p, act: str, *, name_tag=None):
     """Standard transformer MLP. Gated (swiglu/geglu) uses w1 (gate) + w3 (up).
 

@@ -26,6 +26,7 @@ from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models import transformer as T
 from repro.parallel.ctx import Ctx
+from repro.runtime import scopes
 
 
 def _key(rng, *tags):
@@ -477,6 +478,7 @@ class ModelDef:
         return globals_spec(self.cfg)
 
     # ---- execution pieces ---------------------------------------------------
+    @scopes.scoped(scopes.EMBED)
     def embed(self, g, ids, q_pos_local, ctx: Ctx, *, decode=False):
         table = g["embed"]["table"]
         if decode:
@@ -496,6 +498,7 @@ class ModelDef:
             x = x + (emb if pos.ndim == 2 else emb[None])
         return x
 
+    @scopes.scoped(scopes.HEAD_LOSS)
     def head_loss(self, g, x_loc, labels, mask, ctx: Ctx):
         x_loc = L.apply_norm(x_loc, g["final_norm"], self.cfg.norm)
         head = (g["embed"]["table"].T if self.cfg.tie_embeddings

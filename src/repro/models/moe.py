@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel.ctx import Ctx
+from repro.runtime import scopes
 
 
 def moe_dims(cfg, sp: int):
@@ -32,6 +33,7 @@ def moe_dims(cfg, sp: int):
     return E, E // sp
 
 
+@scopes.scoped(scopes.MLP)
 def moe_block(x_loc, p, cfg, ctx: Ctx, *, name_tag=None) -> Tuple[jax.Array, jax.Array]:
     """x_loc: [B, T_loc, d] sequence shard. Returns (y [B,T_loc,d], aux)."""
     moe = cfg.moe

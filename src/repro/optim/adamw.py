@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from repro.runtime import hostmem
+from repro.runtime import hostmem, scopes
 
 # checkpoint-name bases for the host-resident moments; leaf-qualified as
 # opt_m@<leaf-index> so the ledger attributes bytes per leaf exactly
@@ -108,6 +108,7 @@ def global_norm(tree) -> jax.Array:
                         for l in leaves))
 
 
+@scopes.scoped(scopes.ADAMW)
 def apply_update(params, grads, state: AdamWState, *, lr, b1=0.9, b2=0.95,
                  eps=1e-8, weight_decay=0.1, clip_norm=1.0,
                  offload_moments: bool = False,

@@ -40,6 +40,7 @@ from repro.models.transformer import ChunkMeta
 from repro.parallel import specs as SP
 from repro.parallel.ctx import Ctx
 from repro.parallel.plans import resolve_plan
+from repro.runtime import scopes
 
 
 def shard_map(f, mesh, in_specs, out_specs):
@@ -284,12 +285,14 @@ def prefetch_chunk(cell: Cell, ctx: Ctx, *, alpha: float, names: tuple,
         # *cotangents* (integer outputs have float0 tangents — nothing to
         # carry the bytes).  Same byte count either way, so the ledger's
         # act_off accounting is unchanged by the transport view.
-        off_host = tuple(
-            checkpoint_name(hostmem.to_host(hostmem.to_transport(t, codec)),
-                            off_name)
-            for t in off_acts)
-        if mutation.active("double-d2h"):
-            off_host = tuple(hostmem.to_host(t) for t in off_host)
+        with jax.named_scope(scopes.OFFLOAD):
+            off_host = tuple(
+                checkpoint_name(
+                    hostmem.to_host(hostmem.to_transport(t, codec)),
+                    off_name)
+                for t in off_acts)
+            if mutation.active("double-d2h"):
+                off_host = tuple(hostmem.to_host(t) for t in off_host)
         keep_dev = tuple(checkpoint_name(t, keep_name) for t in keep_acts)
         if mutation.active("scale-offloaded"):
             scales = tuple(hostmem.to_host(s) for s in scales)
@@ -321,9 +324,10 @@ def prefetch_chunk(cell: Cell, ctx: Ctx, *, alpha: float, names: tuple,
         # cotangent to the previous chunk's seam.  Reloads stay in wire
         # form across the link — dequantization belongs to the chunk that
         # owns the scales (its own backward, below).
-        staged_prev = jax.tree_util.tree_map(hostmem.to_device, link_in)
-        staged_off = tuple(hostmem.from_transport(t, codec)
-                           for t in staged_off)
+        with jax.named_scope(scopes.OFFLOAD):
+            staged_prev = jax.tree_util.tree_map(hostmem.to_device, link_in)
+            staged_off = tuple(hostmem.from_transport(t, codec)
+                               for t in staged_off)
         if mutation.active("scale-offloaded"):
             # the hosted scales must come back before dequantize can use them
             scale_dev = tuple(hostmem.to_device(s) for s in scale_dev)
@@ -360,7 +364,8 @@ def link_drain(y, link):
         return y, link
 
     def attach_bwd(link_res, ct_y):
-        staged = jax.tree_util.tree_map(hostmem.to_device, link_res)
+        with jax.named_scope(scopes.OFFLOAD):
+            staged = jax.tree_util.tree_map(hostmem.to_device, link_res)
         return ct_y, staged
 
     attach.defvjp(attach_fwd, attach_bwd)
@@ -760,8 +765,9 @@ def make_grad_step(cell: Cell, mesh, *, ledger=None):
     def grad_step(params, batch):
         loss, gs, gg = smapped(params["stages"], params["globals"], batch)
         # the stacked stage grads hold each stage once per dp replica
-        gnorm = jnp.sqrt(jnp.square(adamw.global_norm(gs)) / plan.dp
-                         + jnp.square(adamw.global_norm(gg)))
+        with jax.named_scope(scopes.ADAMW):
+            gnorm = jnp.sqrt(jnp.square(adamw.global_norm(gs)) / plan.dp
+                             + jnp.square(adamw.global_norm(gg)))
         return loss, {"stages": gs, "globals": gg}, gnorm
 
     return grad_step

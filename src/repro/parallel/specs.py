@@ -148,6 +148,20 @@ def count_active_params(mdef: ModelDef, pp: int, data_size: int) -> int:
     return total - dense_of_experts + int(dense_of_experts * active_frac)
 
 
+def count_matmul_params(mdef: ModelDef, pp: int, data_size: int) -> int:
+    """``count_active_params`` less the vectors (norm scales and biases,
+    bias vectors, slot gates): the active weights that multiply
+    activations, the N of MFU's 6·N·T.  Stage leaves are [data, slots,
+    *leaf]."""
+    st = stage_struct(mdef, pp, data_size)
+    gl = globals_struct(mdef)
+    vec = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(st)
+              if l.ndim < 4) * pp // data_size
+    vec += sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(gl)
+               if l.ndim < 2)
+    return count_active_params(mdef, pp, data_size) - vec
+
+
 def L_embed_params(mdef: ModelDef) -> int:
     gl = globals_struct(mdef)
     n = int(np.prod(gl["embed"]["table"].shape))

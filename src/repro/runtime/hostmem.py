@@ -162,7 +162,11 @@ def quantize(t, codec: str):
         amax = jnp.max(jnp.abs(t32), axis=-1, keepdims=True)
     else:
         amax = jnp.abs(t32)
-    scale = jnp.where(amax > 0.0, amax / qmax, 1.0)
+    # floored at the smallest normal float32: a subnormal scale is flushed
+    # to zero on the device, and the row would come back as zeros
+    scale = jnp.where(amax > 0.0,
+                      jnp.maximum(amax / qmax, jnp.finfo(jnp.float32).tiny),
+                      1.0)
     # saturate BEFORE the wire cast for both codecs: t32/scale can land an
     # ulp above qmax depending on how XLA fuses the division (the AD-traced
     # program rearranges it differently than the plain forward), and

@@ -52,15 +52,35 @@ def compile_stats() -> tuple:
     return _counts[_COMPILE_EVENT], _counts[_CACHE_HIT_EVENT]
 
 
+def attention_flops(cfg, *, batch: int, seq: int) -> int:
+    """FLOPs of one training step's causal self-attention, forward and
+    backward (three forwards): 4·head_dim per visible (query, key) pair and
+    query head, seq·(seq + 1)/2 pairs per sequence, in every layer.  0 for
+    the sub-quadratic families, whose mixers are not attention."""
+    if cfg.sub_quadratic:
+        return 0
+    pairs = batch * seq * (seq + 1) // 2
+    return 3 * 4 * cfg.hd * cfg.n_heads * pairs * cfg.n_layers
+
+
 @dataclass
 class Meter:
+    """Per-step TGS and MFU.  MFU counts 6·N·T for the weights (N: the
+    active weights that multiply activations) plus ``attn_flops_per_step``
+    (``attention_flops``); recomputation is not counted."""
     n_chips: int
     tokens_per_step: int
     n_active_params: int
     peak_flops: Optional[float] = None   # per chip; None: report no MFU
+    attn_flops_per_step: int = 0
     history: list = field(default_factory=list)
     _t0: Optional[float] = None
     _programs0: int = 0
+
+    @property
+    def flops_per_step(self) -> int:
+        return (6 * self.n_active_params * self.tokens_per_step
+                + self.attn_flops_per_step)
 
     def start(self):
         self._programs0 = compile_stats()[0]
@@ -70,8 +90,7 @@ class Meter:
         dt = time.perf_counter() - self._t0
         tgs = self.tokens_per_step / dt / self.n_chips  # tokens/chip/s (§7)
         mfu = (None if self.peak_flops is None else
-               6 * self.n_active_params * self.tokens_per_step / dt
-               / (self.n_chips * self.peak_flops))
+               self.flops_per_step / dt / (self.n_chips * self.peak_flops))
         rec = {"step": step, "loss": float(loss), "dt": dt,
                "tgs": tgs, "mfu": mfu,
                "compiles": compile_stats()[0] - self._programs0}
