@@ -8,17 +8,30 @@ token positions, so the same kernel serves intra-chunk causal attention,
 cross-chunk cache attention, decode (Tq == 1 padded to a block) and
 bidirectional encoder attention (causal=False).
 
-TPU mapping (target: v5e — MXU 128x128, ~16 MiB VMEM/core):
+TPU mapping (target: v5e — MXU 128x128, 16 MiB scoped VMEM/core):
   grid = (B * Hkv, Tq // bq, S // bk) with the KV dimension innermost
   ("arbitrary" semantics) so the (m, l, acc) accumulators live in VMEM
-  scratch across KV steps.  Block shapes default to (bq=128, bk=128) * G
-  query rows — q rows for all G grouped query heads of one KV head are
-  folded into the q-block row dimension, so GQA costs no extra KV traffic:
-  the [bk, hd] KV block is streamed once per q block for all G heads.
+  scratch across KV steps.  A block holds G * bq query rows — q rows for
+  all G grouped query heads of one KV head are folded into the q-block row
+  dimension, so GQA costs no extra KV traffic: the [bk, hd] KV block is
+  streamed once per q block for all G heads.
 
-VMEM budget at defaults (bq=128, bk=128, hd=128, G<=8, fp32 accum):
-  q (G*128*128*4) + k/v (2*128*128*4) + acc (G*128*128*4) + p (G*128*128*4)
-  ~= 3.3 MiB at G=8 — comfortably inside 16 MiB with double buffering.
+Tiles (``_tiles``) are chosen from the call's static shapes, not fixed:
+  bk is the largest of 1024/512/256/128 dividing S rounded up to 128 (no
+  padding beyond 128's), bq the largest power of two dividing Tq rounded up
+  to 128 with G * bq <= 512 rows, and lengths below a tile take the length
+  rounded up to 8 (decode: bq = 8).  Most of a kernel's time at small
+  tiles is per grid step and per row (the online-softmax row updates, the
+  lane-padded [G*bq, 1] position and statistic blocks), so larger tiles
+  amortise it: on a v5e at the 16K cell's chunks, 512 x 512 and
+  512 x 1024 run the three kernels about 5x faster than 128 x 128
+  (PERF.md §6).
+
+VMEM budget (``_vmem_bytes``, everything counted as fp32): three
+  [G*bq, bk] score tiles, the upcast q/do/k/v blocks, double-buffered
+  input blocks and the larger accumulator side; bk, then bq, halves until
+  it is under the 16 MiB default.  512 x 1024 at hd 128 estimates 15.6 MiB
+  where the TPU compiler needs 10.2 (dkv, the largest pass there).
 
 Outputs are the *partial* (o, m, l) triple (see kernels/ref.py) so the
 cross-device softmax merge (psum over the `model` axis) composes with the
@@ -50,6 +63,7 @@ formulation.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +73,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 PAD_POS = 2**30
+
+log = logging.getLogger(__name__)
 
 
 def _flash_partial_kernel(qpos_ref, kpos_ref, qstart_ref,  # position blocks
@@ -196,11 +212,69 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _geometry(Tq: int, S: int, block_q: int, block_k: int):
-    bq = min(block_q, _round_up(Tq, 8))
-    bk = min(block_k, _round_up(S, 8))
+KV_TILES = (1024, 512, 256, 128)            # bk candidates, largest first
+Q_TILES = tuple(2 ** i for i in range(10, 2, -1))   # bq: 1024 ... 8
+ROW_CAP = 512           # folded q rows (G·bq) a block may hold
+VMEM_BUDGET = 16 << 20  # the scoped-VMEM default of a v5e core
+
+
+def _vmem_bytes(rows: int, bk: int, hd_k: int, hd_v: int) -> int:
+    """Estimated live VMEM of the larger backward pass at ``rows`` = G·bq
+    folded q rows, every element counted as fp32: three [rows, bk] tiles
+    (p, dp, ds; s dies into p), the upcast q/do/k/v blocks, the
+    double-buffered input blocks (the [rows, 1] position and statistic
+    columns pad to 128 lanes, the [1, bk] kv positions to 8 sublanes), and
+    the accumulator with its double-buffered output block — dq on the q
+    side in the dq pass, dk/dv on the kv side in the dkv pass."""
+    hd = hd_k + hd_v
+    tiles = 3 * rows * bk
+    upcast = (rows + bk) * hd
+    blocks = (rows + bk) * hd + 4 * rows * 128 + 8 * bk
+    acc = 3 * max(rows * hd_k, bk * hd)
+    return 4 * (tiles + upcast + 2 * blocks + acc)
+
+
+def _tiles(G: int, Tq: int, S: int, hd_k: int, hd_v: int):
+    """Block geometry (bq, bk) of the forward, dq and dkv grids, from the
+    call's static shapes.
+
+    bk is the largest of ``KV_TILES`` that divides S rounded up to 128, so
+    no padding is streamed beyond 128's; bq the largest power of two from
+    1024 down to 8 that divides Tq rounded up to 128 with G·bq at most
+    ``ROW_CAP`` (8 where even that exceeds it).  While the estimated VMEM
+    exceeds ``VMEM_BUDGET``, bk halves down to 128, then bq down to 8.  A
+    length below its tile takes the length rounded up to 8 (decode keeps
+    bq = 8)."""
+    bk = next(t for t in KV_TILES if _round_up(S, 128) % t == 0)
+    bq = next((t for t in Q_TILES
+               if G * t <= ROW_CAP and _round_up(Tq, 128) % t == 0), 8)
+    while _vmem_bytes(G * bq, bk, hd_k, hd_v) > VMEM_BUDGET:
+        if bk > KV_TILES[-1]:
+            bk //= 2
+        elif bq > Q_TILES[-1]:
+            bq //= 2
+        else:
+            break
+    return min(bq, _round_up(Tq, 8)), min(bk, _round_up(S, 8))
+
+
+@functools.lru_cache(maxsize=None)     # once per attention shape
+def _log_tiles(G, Tq, S, hd_k, hd_v, bq, bk, nq, nk):
+    log.info("flash tiles: G %d Tq %d S %d hd %d/%d -> bq %d bk %d, "
+             "%d x %d grid steps per KV head", G, Tq, S, hd_k, hd_v, bq, bk,
+             nq, nk)
+
+
+def _geometry(G, Tq, S, hd_k, hd_v, block_q, block_k):
+    """(bq, bk, Tqp, Sp, nq, nk); a block left None takes ``_tiles``'s."""
+    auto_q, auto_k = _tiles(G, Tq, S, hd_k, hd_v)
+    bq = min(block_q or auto_q, _round_up(Tq, 8))
+    bk = min(block_k or auto_k, _round_up(S, 8))
     Tqp, Sp = _round_up(Tq, bq), _round_up(S, bk)
-    return bq, bk, Tqp, Sp, Tqp // bq, Sp // bk
+    nq, nk = Tqp // bq, Sp // bk
+    if block_q is None or block_k is None:
+        _log_tiles(G, Tq, S, hd_k, hd_v, bq, bk, nq, nk)
+    return bq, bk, Tqp, Sp, nq, nk
 
 
 def _pad_inputs(q, k, v, q_pos, kv_pos, q_start, Tqp, Sp):
@@ -270,7 +344,8 @@ def _fwd_impl(q, k, v, q_pos, kv_pos, q_start, causal, scale, block_q,
     S, Hkv = k.shape[1], k.shape[2]
     hdv = v.shape[-1]
     G = H // Hkv
-    bq, bk, Tqp, Sp, nq, nk = _geometry(Tq, S, block_q, block_k)
+    bq, bk, Tqp, Sp, nq, nk = _geometry(G, Tq, S, hdk, hdv, block_q,
+                                        block_k)
     q, k, v, q_pos, kv_pos, q_start = _pad_inputs(
         q, k, v, q_pos, kv_pos, q_start, Tqp, Sp)
 
@@ -322,7 +397,8 @@ def _bwd_impl(q, k, v, q_pos, kv_pos, q_start, do, m, dl, causal, scale,
     S, Hkv = k.shape[1], k.shape[2]
     hdv = v.shape[-1]
     G = H // Hkv
-    bq, bk, Tqp, Sp, nq, nk = _geometry(Tq, S, block_q, block_k)
+    bq, bk, Tqp, Sp, nq, nk = _geometry(G, Tq, S, hdk, hdv, block_q,
+                                        block_k)
     # fully-masked rows (m == NEG_INF) have o == l == 0 identically; their
     # cotangents are meaningless and can be inf/NaN (the 1/l² of the
     # downstream quotient rule overflows fp32) — zero them so 0·NaN can't
@@ -445,7 +521,7 @@ _flash_partial.defvjp(_flash_partial_fwd, _flash_partial_bwd)
 
 
 def flash_attention_partial(q, k, v, q_pos, kv_pos, *, causal=True,
-                            scale=None, block_q=128, block_k=128,
+                            scale=None, block_q=None, block_k=None,
                             interpret=False, q_start=None):
     """Pallas partial flash attention (differentiable in q, k, v).
 
@@ -454,6 +530,8 @@ def flash_attention_partial(q, k, v, q_pos, kv_pos, *, causal=True,
     q_start: optional [B, Tq] or [Tq] segment window — kv slots below
     q_start are masked (packed-document blocking); None degenerates to the
     plain positional mask (a zero window changes no visibility bit).
+    block_q / block_k: the q and kv block lengths; None (the default) takes
+    the shape-chosen ``_tiles``.
     Returns (o [B,Tq,H,hd_v] f32 un-normalized, m [B,Tq,H] f32, l [B,Tq,H] f32).
     """
     if scale is None:
@@ -466,5 +544,4 @@ def flash_attention_partial(q, k, v, q_pos, kv_pos, *, causal=True,
     elif q_start.ndim == 1:
         q_start = jnp.broadcast_to(q_start[None, :], (B, Tq))
     return _flash_partial(q, k, v, q_pos, kv_pos, q_start, bool(causal),
-                          float(scale), int(block_q), int(block_k),
-                          bool(interpret))
+                          float(scale), block_q, block_k, bool(interpret))

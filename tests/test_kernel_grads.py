@@ -134,6 +134,48 @@ def test_grad_decode_padded_block():
                                    err_msg=f"d{name}")
 
 
+# (Tq, S, G, causal, dtype): lengths the shape-chosen tiles split into
+# several blocks and pad — 512 x 1024 at G 1, 128 x 1024 at G 4 (row cap),
+# and decode: 8 x 1024 over 4 kv blocks
+AUTO_CASES = [
+    (1000, 2000, 1, True, jnp.float32),
+    (1000, 2000, 4, True, jnp.float32),
+    (300, 600, 2, False, jnp.float32),
+    (1000, 2000, 1, True, jnp.bfloat16),
+    (1, 4000, 7, True, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("Tq,S,G,causal,dtype", AUTO_CASES)
+def test_grad_auto_tiles_match_ref(Tq, S, G, causal, dtype):
+    """dq/dk/dv at the tiles the kernel picks itself (several blocks, with
+    padding) match the blockwise reference's autodiff."""
+    H, Hkv, hd = 2 * G, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(Tq + G), 4)
+    q = jax.random.normal(ks[0], (1, Tq, H, hd), dtype)
+    k = jax.random.normal(ks[1], (1, S, Hkv, hd), dtype)
+    v = jax.random.normal(ks[2], (1, S, Hkv, hd), dtype)
+    w = jax.random.normal(ks[3], (1, Tq, H, hd), jnp.float32)
+    q_pos = jnp.arange(Tq, dtype=jnp.int32) + (S - Tq)
+    kv_pos = jnp.where(jnp.arange(S) < S - 7, jnp.arange(S), PAD_POS)
+
+    def loss(fn):
+        def f(q, k, v):
+            o, _, l = fn(q, k, v)
+            return jnp.sum(normalize(o, l) * w)
+        return f
+
+    gp = _grads(loss(lambda q, k, v: flash_attention_partial(
+        q, k, v, q_pos, kv_pos, causal=causal, interpret=True)), q, k, v)
+    gr = _grads(loss(lambda q, k, v: attention_partial_ref(
+        q, k, v, q_pos, kv_pos, causal=causal, block_k=16)), q, k, v)
+    tol = TOL[dtype]
+    for name, a, b in zip("qkv", gp, gr):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=tol,
+                                   atol=tol, err_msg=f"d{name}")
+
+
 # ---------------------------------------------------------------------------
 # Merge gradients: the stop_gradient on the max stat must not freeze anything
 # ---------------------------------------------------------------------------

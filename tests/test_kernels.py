@@ -12,6 +12,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ref import (attention_partial_ref, merge_partials,
                                mha_reference, normalize)
+from repro.kernels import flash_attention as fa
 from repro.kernels.flash_attention import flash_attention_partial
 
 
@@ -49,20 +50,110 @@ def test_ref_blockwise_matches_naive(B, Tq, S, H, Hkv, hd, hv, causal, qoff, dty
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("B,Tq,S,H,Hkv,hd,hv,causal,qoff,dtype", SWEEP)
-def test_pallas_matches_ref(B, Tq, S, H, Hkv, hd, hv, causal, qoff, dtype):
+# lengths the shape-chosen tiles split into several blocks, and pad:
+# (300, 600) at G 2 takes 128 x 128 (nq 3, nk 5), (1000, 2000) at G 1
+# takes 512 x 1024 (nq 2, nk 2)
+AUTO_SWEEP = [
+    (1, 300, 600, 4, 2, 32, 32, True, 300, jnp.float32),
+    (1, 1000, 2000, 2, 2, 16, 16, True, 1000, jnp.float32),
+    (1, 1000, 2000, 2, 2, 16, 16, False, 0, jnp.bfloat16),
+]
+
+
+def _case_id(case):
+    return "-".join(getattr(x, "__name__", str(x)) for x in case)
+
+
+# block length 16 everywhere, or None: the tiles the kernel picks itself
+BLOCKED = ([pytest.param(*c, 16, id=_case_id(c)) for c in SWEEP]
+           + [pytest.param(*c, None, id="auto-" + _case_id(c))
+              for c in SWEEP + AUTO_SWEEP])
+
+
+@pytest.mark.parametrize("B,Tq,S,H,Hkv,hd,hv,causal,qoff,dtype,block", BLOCKED)
+def test_pallas_matches_ref(B, Tq, S, H, Hkv, hd, hv, causal, qoff, dtype,
+                            block):
     q, k, v = _mk(B, Tq, S, H, Hkv, hd, hv, dtype)
     q_pos = jnp.arange(Tq, dtype=jnp.int32) + qoff
     kv_pos = jnp.arange(S, dtype=jnp.int32)
     o1, m1, l1 = attention_partial_ref(q, k, v, q_pos, kv_pos, causal=causal,
                                        block_k=16)
     o2, m2, l2 = flash_attention_partial(q, k, v, q_pos, kv_pos,
-                                         causal=causal, block_q=16,
-                                         block_k=16, interpret=True)
+                                         causal=causal, block_q=block,
+                                         block_k=block, interpret=True)
     got = np.asarray(normalize(o2, l2))
     want = np.asarray(normalize(o1, l1))
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+# ---------------------------------------------------------------------------
+# The tile rule: block geometry chosen from the call's static shapes
+# ---------------------------------------------------------------------------
+
+CELL_CHUNKS = [(9728, 9728), (6656, 16384)]    # gpt7b-16k-1chip, Tq vs S
+RULE_SHAPES = CELL_CHUNKS + [(2048, 2048), (1, 4096), (300, 600), (17, 33),
+                             (1000, 2000), (130, 9000)]
+
+
+def _padding_at_128(Tq, S):
+    """(Tqp, Sp) of fixed 128 x 128 blocks, the kernels' old default."""
+    bq, bk = min(128, fa._round_up(Tq, 8)), min(128, fa._round_up(S, 8))
+    return fa._round_up(Tq, bq), fa._round_up(S, bk)
+
+
+@pytest.mark.parametrize("G", [1, 7, 16])
+@pytest.mark.parametrize("Tq,S", RULE_SHAPES)
+def test_tiles_pad_no_more_than_fixed_blocks(Tq, S, G):
+    _, _, Tqp, Sp, _, _ = fa._geometry(G, Tq, S, 128, 128, None, None)
+    old_q, old_k = _padding_at_128(Tq, S)
+    assert Tqp <= old_q and Sp <= old_k
+    if (Tq, S) in CELL_CHUNKS:
+        assert (Tqp, Sp) == (Tq, S)
+
+
+def test_tiles_of_the_cell_chunks():
+    """The chip sweep's winner at the cell's chunks: 512-row q blocks,
+    kv blocks of 512 at S 9728 (19 x 512) and 1024 at S 16384."""
+    assert fa._tiles(1, 9728, 9728, 128, 128) == (512, 512)
+    assert fa._tiles(1, 6656, 16384, 128, 128) == (512, 1024)
+
+
+@pytest.mark.parametrize("G", [1, 2, 4, 7, 8, 16])
+@pytest.mark.parametrize("Tq,S", RULE_SHAPES)
+def test_tiles_cap_folded_rows(Tq, S, G):
+    bq, bk = fa._tiles(G, Tq, S, 128, 128)
+    assert G * bq <= fa.ROW_CAP and bq % 8 == 0
+    assert bk % 128 == 0 or bk == fa._round_up(S, 8)
+
+
+@pytest.mark.parametrize("hd_k,hd_v,G", [(128, 128, 1), (128, 128, 7),
+                                         (128, 128, 16), (576, 512, 1),
+                                         (576, 512, 8)])
+def test_tiles_fit_the_vmem_budget(hd_k, hd_v, G):
+    for Tq, S in RULE_SHAPES:
+        bq, bk = fa._tiles(G, Tq, S, hd_k, hd_v)
+        assert fa._vmem_bytes(G * bq, bk, hd_k, hd_v) <= fa.VMEM_BUDGET
+
+
+def test_vmem_estimate_shrinks_kv_first():
+    """MLA widths (576/512) at G 8: the q block stays at the row cap while
+    the kv block halves to fit the budget."""
+    bq, bk = fa._tiles(8, 2048, 2048, 576, 512)
+    assert 8 * bq == fa.ROW_CAP and bk < 1024
+
+
+@pytest.mark.parametrize("G", [1, 7, 16])
+def test_tiles_decode_keeps_eight_rows(G):
+    assert fa._tiles(G, 1, 4096, 128, 128)[0] == 8
+
+
+def test_explicit_blocks_are_honoured():
+    assert fa._geometry(1, 300, 600, 16, 16, 16, 16)[:2] == (16, 16)
+    bq, bk = fa._geometry(1, 2048, 2048, 128, 128, 256, None)[:2]
+    assert (bq, bk) == (256, fa._tiles(1, 2048, 2048, 128, 128)[1])
+    bq, bk = fa._geometry(1, 2048, 2048, 128, 128, None, 128)[:2]
+    assert (bq, bk) == (fa._tiles(1, 2048, 2048, 128, 128)[0], 128)
 
 
 def test_partial_merge_equals_full():
