@@ -8,6 +8,10 @@ implementation does.
 """
 from __future__ import annotations
 
+import math
+
+from . import references
+
 
 def attention_pairs(B: int, T: int, S: int, causal: bool) -> int:
     """(query, key) pairs one head scores.  Causal training attention puts
@@ -45,12 +49,13 @@ def attention_bwd(*, B, H, Hkv, T, S, head_dim, causal=True,
 
 
 def matmul_params(model) -> int:
-    """Weights that multiply activations: the layers' projections and the
-    head.  The embedding is a lookup and the norms are elementwise."""
-    qd, kvd = model.heads * model.head_dim, model.kv_heads * model.head_dim
-    per_layer = model.d * qd + 2 * model.d * kvd + qd * model.d \
-        + 2 * model.d * model.ff
-    return model.layers * per_layer + model.d * model.vocab
+    """Weights that multiply each token, as the reference's leaf table
+    states their share (``Leaf.matmul``): the layers' projections and the
+    head whole, routed experts by the share of them a token visits.  The
+    embedding is a lookup, and norms and biases are elementwise."""
+    table = references.of(model).leaves(model)
+    return round(sum(math.prod(lf.shape) * lf.matmul
+                     for lf in table.values()))
 
 
 def train_step_flops(model, *, batch: int, seq: int) -> int:

@@ -23,14 +23,12 @@ import tempfile
 import time
 
 from . import adamw as ref_adamw
-from . import check, spec, tracefile, weights
+from . import check, references, scopes, spec, tracefile, weights
 from .traffic import TokenFeed
 
 CACHE_DIR = os.path.join(spec.ROOT, ".jax_cache")
-# the program's architecture fields this benchmark's configurations state
-PROGRAM_FIELDS = {"act": {"gelu_tanh": "gelu"}, "norm": {"layernorm":
-                                                         "layernorm"}}
-MATRICES = ("wq", "wk", "wv", "wo", "w1", "w2", "embed", "head")
+# the program's name for an activation a configuration file names
+PROGRAM_ACT = {"gelu_tanh": "gelu", "silu": "swiglu"}
 
 
 class NoChip(RuntimeError):
@@ -92,10 +90,47 @@ def _import_program():
         sys.path.insert(0, src)
 
 
+def stated(model) -> dict:
+    """{program field: (configuration key, the file's value, the value the
+    program has to hold)} for every architecture field a configuration
+    states; an absent optional key states its default (``spec.OPTIONAL``)."""
+    pos = model.position_embedding_type
+    return {
+        "act": ("hidden_act", model.act, PROGRAM_ACT.get(model.act)),
+        "norm": ("norm", model.norm, model.norm),
+        "qkv_bias": ("attention_bias", model.attention_bias,
+                     model.attention_bias),
+        "mlp_bias": ("mlp_bias", model.mlp_bias, model.mlp_bias),
+        "pos_emb": ("position_embedding_type", pos, pos),
+        "rope": ("position_embedding_type", pos, pos == "rope"),
+        "rope_theta": ("rope_theta", model.rope_theta, model.rope_theta),
+        "rope_fraction": ("partial_rotary_factor",
+                          model.partial_rotary_factor,
+                          model.partial_rotary_factor),
+        "tie_embeddings": ("tie_word_embeddings", model.tie_word_embeddings,
+                           model.tie_word_embeddings),
+        "dtype": ("dtype", model.dtype, model.dtype),
+        "family": ("family", model.family, model.family),
+    }
+
+
+def program_norm_eps(cfg) -> float:
+    """The eps the program's norms run at: ``layers.apply_norm`` passes
+    none, so each norm runs at its default."""
+    import inspect
+
+    from repro.models import layers
+
+    fn = {"rmsnorm": layers.rms_norm, "layernorm": layers.layer_norm}[
+        cfg.norm]
+    return inspect.signature(fn).parameters["eps"].default
+
+
 def program_config(model):
     """The program's ModelConfig for ``model``: its registered architecture
-    with the file's sizes; a field the file states differently is an
-    error, not an override."""
+    with the file's sizes.  Every architecture field the file states has to
+    be the program's: a difference is an error naming both values, not an
+    override."""
     from repro.configs.base import get_config
 
     cfg = get_config(model.program_arch)
@@ -103,17 +138,15 @@ def program_config(model):
         cfg, n_layers=model.layers, d_model=model.d, n_heads=model.heads,
         n_kv_heads=model.kv_heads, head_dim=model.head_dim, d_ff=model.ff,
         vocab_size=model.vocab)
-    want = {"act": PROGRAM_FIELDS["act"].get(model.act),
-            "norm": PROGRAM_FIELDS["norm"].get(model.norm),
-            "rope": True, "rope_theta": model.rope_theta,
-            "rope_fraction": 1.0, "pos_emb": "rope", "qkv_bias": False,
-            "mlp_bias": False, "tie_embeddings": False,
-            "dtype": model.dtype, "family": "dense"}
-    for k, v in want.items():
-        if getattr(cfg, k) != v:
-            raise ValueError(f"{model.program_arch}: program has {k}="
-                             f"{getattr(cfg, k)!r}, the configuration "
-                             f"states {v!r}")
+    fields = {k: (key, v, want, getattr(cfg, k))
+              for k, (key, v, want) in stated(model).items()}
+    fields["norm eps"] = ("norm_eps", model.norm_eps, model.norm_eps,
+                          program_norm_eps(cfg))
+    for k, (key, v, want, have) in fields.items():
+        if have != want:
+            raise ValueError(f"{model.program_arch}: the program has {k}="
+                             f"{have!r}, the configuration states {key}="
+                             f"{v!r}")
     return cfg
 
 
@@ -154,10 +187,11 @@ class Program:
         struct, specs = SP.param_struct_and_specs(mdef, plan.pp, data,
                                                   self.cell.dtype)
         pshard = jax.tree.map(lambda s: NamedSharding(self.mesh, s), specs)
-        self.slots = struct["stages"]["attn"]["wq"].shape[1]
+        self.slots = weights.slots(struct)
+        self.table = table = references.of(m).leaves(m)
         init = jax.jit(lambda key: weights.to_program(
-            weights.canonical(m, key), struct, pp=plan.pp, data=data),
-            out_shardings=pshard)
+            weights.canonical(table, key), table, struct, pp=plan.pp,
+            data=data), out_shardings=pshard)
         self.key = weights.seed_key(seed)
         params = init(self.key)
         opt_state = adamw.init_state(
@@ -197,9 +231,8 @@ class Program:
         return {k: jax.device_put(v, self.bshard[k]) for k, v in batch.items()}
 
     def _leaves(self, tree):
-        for name, path in weights.PROGRAM_PATHS.items():
-            part = "stages" if name in weights.LAYER_LEAVES else "globals"
-            yield name, weights._get(tree[part], path)
+        for name, lf in self.table.items():
+            yield name, lf, weights._get(tree[weights.part(lf)], lf.path)
 
     def _norms_of(self, tree, key=None):
         """Norm of every weight of a program tree, in canonical layout;
@@ -210,22 +243,23 @@ class Program:
         import jax.numpy as jnp
 
         out, prev = {}, None
-        for name, leaf in self._leaves(tree):
+        for name, lf, leaf in self._leaves(tree):
             if prev is not None:
                 leaf, _ = jax.lax.optimization_barrier((leaf, prev))
             x = weights.program_rows(jax.device_put(leaf,
                                                     jax.memory.Space.Device),
-                                     name, self.model, self.slots)
+                                     lf, self.slots)
             x = x.astype(jnp.float32)
             if key is not None:
-                x = x - weights.leaf(self.model, key, name).astype(jnp.float32)
-            out[name] = prev = weights.norm(x, name)
+                x = x - weights.leaf(self.table, key, name).astype(
+                    jnp.float32)
+            out[name] = prev = weights.norms(x, lf)
         return out
 
     def _named(self, norms) -> dict:
         out = {}
         for name, v in norms.items():
-            out.update(weights.named(name, v))
+            out.update(weights.named(name, v, self.table[name].per_layer))
         return out
 
     def moment_norms(self, b1: float) -> dict:
@@ -252,20 +286,22 @@ def reference_readings(cell: spec.Cell, seed: int, steps: int,
     import jax.numpy as jnp
 
     m = cell.model
-    mod = importlib.import_module(f"{__package__}.references.{m.reference}")
-    model = mod.GPT(m, precision=precision)
+    mod = references.of(m)
+    table = mod.leaves(m)
+    model = mod.Reference(m, precision=precision)
     feed = TokenFeed(cell.traffic, m.vocab, seed)
     key = weights.seed_key(seed)
-    make = jax.jit(lambda k: weights.canonical(m, k))
-    params = mod.from_canonical(make(key), m.layers)
+    make = jax.jit(lambda k: weights.canonical(table, k))
+    params = weights.by_layer(make(key), table)
+    decayed = {name for name, lf in table.items() if lf.decay}
     opt = ref_adamw.AdamW(cell.optimizer, jnp.bfloat16,
-                          lambda path: path[-1].key in MATRICES)
+                          lambda path: path[-1].key in decayed)
     sq = jax.jit(lambda g: jnp.sqrt(jnp.sum(jnp.square(g))))
     losses, grad = [], {}
     for s in range(steps):
         loss, grads = model.loss_and_grads(params, *feed.batch(s))
         losses.append(loss)
-        norms = _leaf_norms(grads, sq)
+        norms = _leaf_norms(grads, table, sq)
         gnorm = math.sqrt(sum(v * v for v in norms.values()))
         if s == 0:
             clip = min(1.0, cell.optimizer["clip_norm"] / max(gnorm, 1e-12))
@@ -273,23 +309,35 @@ def reference_readings(cell: spec.Cell, seed: int, steps: int,
         params = opt.update(params, grads, s, gnorm, keep=s < steps - 1)
         del grads
     change = {}
-    for name in weights.LAYER_LEAVES + weights.GLOBAL_LEAVES:
-        d = jax.jit(lambda p, k, n=name: weights.norm(
-            (jnp.stack(p) if n in weights.LAYER_LEAVES else p
-             ).astype(jnp.float32) - weights.leaf(m, k, n).astype(jnp.float32), n))
+    for name, lf in table.items():
+        d = jax.jit(lambda p, k, n=name, lf=lf: weights.norms(
+            (jnp.stack(p) if lf.per_layer else p).astype(jnp.float32)
+            - weights.leaf(table, k, n).astype(jnp.float32), lf))
         cur = ([lay[name] for lay in params["layers"]]
-               if name in weights.LAYER_LEAVES else params[name])
-        change.update(weights.named(name, d(cur, key)))
+               if lf.per_layer else params[name])
+        change.update(weights.named(name, d(cur, key), lf.per_layer))
     return {"loss": losses, "grad": grad, "change": change}
 
 
-def _leaf_norms(grads, sq) -> dict:
+def _leaf_norms(grads, table, sq) -> dict:
+    import jax.numpy as jnp
+
     out = {}
+
+    def add(label, name, g):
+        parts = table[name].parts
+        if not parts:
+            out[label] = float(sq(g))
+            return
+        for part, mask in parts.items():
+            out[f"{label}[{part}]"] = float(sq(jnp.where(mask, g, 0)))
+
     for l, layer in enumerate(grads["layers"]):
         for k, g in layer.items():
-            out[f"L{l}.{k}"] = float(sq(g))
-    for k in weights.GLOBAL_LEAVES:
-        out[k] = float(sq(grads[k]))
+            add(f"L{l}.{k}", k, g)
+    for k, lf in table.items():
+        if not lf.per_layer:
+            add(k, k, grads[k])
     return out
 
 
@@ -305,6 +353,7 @@ class Readings:
     compiles_in_window: int
     trace: dict             # tracefile.load form
     hlo: dict               # tracefile.hlo_ops of the step
+    scopes: dict            # scopes.hlo_scopes of the step: its op_names
 
 
 def read_metric(name: str, r: Readings):
@@ -420,7 +469,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
                 f.write(prog_hlo)
         r = Readings(cell=cell, peak=peak, chips=chips, steps=steps,
                      window_s=window_s, compiles_in_window=compiles,
-                     trace=tr, hlo=hlo)
+                     trace=tr, hlo=hlo, scopes=scopes.hlo_scopes(prog_hlo))
         metrics = {}
         for name in cell.per_layer:
             v = read_metric(name, r)

@@ -5,5 +5,5 @@ from .. import scopes
 
 
 def read(r):
-    names = scopes.scopes_of(r)
+    names = r.scopes
     return scopes.ms_per_step(r, lambda op: scopes.is_recompute(names.get(op)))

@@ -27,9 +27,46 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..weights import LAYER_LEAVES
+from . import Leaf
 
 HIGHEST = lax.Precision.HIGHEST
+
+
+def leaves(m) -> dict:
+    """The leaf table: the program's initialisation scheme (truncated
+    normals at 1/sqrt(fan-in); output projections scaled by 1/sqrt(2 *
+    published depth); norms at gain 1, bias 0).  Matrices and the embedding
+    take weight decay."""
+    L, d, ff = m.layers, m.d, m.ff
+    qd, kvd = m.heads * m.head_dim, m.kv_heads * m.head_dim
+    out_scale = 1.0 / math.sqrt(2 * m.published_layers)
+
+    def layer(shape, path, **kw):
+        return Leaf((L,) + shape, path, per_layer=True, **kw)
+
+    def matrix(shape, path, std):
+        return layer(shape, path, std=std, decay=True, matmul=1.0)
+
+    return {
+        "ln1_scale": layer((d,), ("ln1", "scale"), fill=1.0),
+        "ln1_bias": layer((d,), ("ln1", "bias")),
+        "wq": matrix((d, qd), ("attn", "wq"), 1 / math.sqrt(m.d)),
+        "wk": matrix((d, kvd), ("attn", "wk"), 1 / math.sqrt(m.d)),
+        "wv": matrix((d, kvd), ("attn", "wv"), 1 / math.sqrt(m.d)),
+        "wo": matrix((qd, d), ("attn", "wo"),
+                     out_scale / math.sqrt(m.heads * m.head_dim)),
+        "ln2_scale": layer((d,), ("ln2", "scale"), fill=1.0),
+        "ln2_bias": layer((d,), ("ln2", "bias")),
+        "w1": matrix((d, ff), ("mlp", "w1"), 1 / math.sqrt(m.d)),
+        "w2": matrix((ff, d), ("mlp", "w2"), out_scale / math.sqrt(m.ff)),
+        "embed": Leaf((m.vocab, d), ("embed", "table"), per_layer=False,
+                      std=0.02, decay=True),
+        "head": Leaf((d, m.vocab), ("head", "w"), per_layer=False,
+                     std=1 / math.sqrt(m.d), decay=True, matmul=1.0),
+        "lnf_scale": Leaf((d,), ("final_norm", "scale"), per_layer=False,
+                          fill=1.0),
+        "lnf_bias": Leaf((d,), ("final_norm", "bias"), per_layer=False),
+    }
 
 
 def _quantize(x, dtype):
@@ -102,7 +139,7 @@ def _f32(tree):
     return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
 
 
-class GPT:
+class Reference:
     def __init__(self, model, *, precision: str = "float32",
                  q_span: int = 4096, q_block: int = 1024,
                  head_group: int = 8, token_block: int = 2048):
@@ -248,11 +285,3 @@ class GPT:
         return float(loss), {"layers": g_layers, "embed": g_emb,
                              "head": g_h, "lnf_scale": g_s, "lnf_bias": g_b}
 
-
-def from_canonical(canon: dict, layers: int) -> dict:
-    """The layout ``GPT`` takes, from the benchmark's canonical leaves
-    (per-layer leaves stacked on a leading axis)."""
-    out = {k: v for k, v in canon.items() if k not in LAYER_LEAVES}
-    out["layers"] = [{k: canon[k][l] for k in LAYER_LEAVES}
-                     for l in range(layers)]
-    return out

@@ -10,13 +10,9 @@ operation that ``jax.checkpoint`` recomputes in the backward.  The names
 are kept here, not imported, so the benchmark reads a program that lacks
 them (an older one) as having nothing to read.
 
-Where the op_names come from: the harness hands a reader the step's
-kernels, host copies and collectives (``tracefile.hlo_ops``) but not its
-op_names.  ``scopes_of`` takes them from ``r.hlo["scopes"]`` where the
-caller put them there (the recorded trace does), else from the executable
-the runtime still holds for the step -- the harness keeps the step alive
-while the readers run -- found as the live executable that defines the
-most of the instructions the trace's events name.
+The harness hands a reader the step's op_names (``Readings.scopes``, from
+``hlo_scopes`` of the compiled step's HLO text) beside its kernels, host
+copies and collectives (``tracefile.hlo_ops``).
 
 Host copies: XLA leaves most of the copies it makes without an ``op_name``
 and ``hlo_scopes`` gives them that of the value they move or the operation a
@@ -42,7 +38,6 @@ LAYERS = (EMBED, NORM, ATTN, MLP, HEAD_LOSS, ADAMW, OFFLOAD)
 
 _SCOPE = re.compile(r"sppo\.[a-z_]+")
 _RECOMPUTE = re.compile(r"(?:^|[/(])rematted_computation(?:[/)]|$)")
-_DEFINED = re.compile(r"^\s*(?:ROOT\s+)?%([^\s=]+)\s*=", re.M)
 _COPIES = ("copy", "copy-start", "copy-done")
 
 
@@ -102,39 +97,6 @@ def hlo_scopes(hlo_text: str) -> dict:
     return found
 
 
-def live_hlo_texts() -> list:
-    """The HLO text of each executable the runtime holds, as
-    ``jax.stages.Compiled.as_text`` reads it; those with no text view are
-    left out."""
-    import jax
-
-    texts = []
-    for exe in jax.devices()[0].client.live_executables():
-        try:
-            texts.append(exe.get_hlo_text() if hasattr(exe, "get_hlo_text")
-                         else "\n\n".join(m.to_string()
-                                           for m in exe.hlo_modules()))
-        except (NotImplementedError, RuntimeError):
-            continue
-    return texts
-
-
-def scopes_of(r) -> dict:
-    """``hlo_scopes`` of the step the trace ran (see the module's note);
-    {} where no live executable defines an instruction the trace names.
-    Kept in ``r.hlo["scopes"]`` for the next reader."""
-    if "scopes" not in r.hlo:
-        ops = {e[0] for events in r.trace["devices"].values()
-               for e in events}
-        best, hits = None, 0
-        for text in live_hlo_texts():
-            n = len(ops & set(_DEFINED.findall(text)))
-            if n > hits:
-                best, hits = text, n
-        r.hlo["scopes"] = hlo_scopes(best) if best else {}
-    return r.hlo["scopes"]
-
-
 def layer_of(op_name: str | None) -> str | None:
     """The innermost of LAYERS in an ``op_name`` path, also inside
     transformation wrappers (``transpose(jvp(sppo.attn))``); None if none."""
@@ -161,7 +123,7 @@ def is_host_copy(r, op: str) -> bool:
 def layer(r, op: str) -> str | None:
     """The layer of an instruction: its innermost scope; a host copy with
     none is an activation transfer, OFFLOAD (see the module's note)."""
-    found = layer_of(scopes_of(r).get(op))
+    found = layer_of(r.scopes.get(op))
     if found is None and is_host_copy(r, op):
         return OFFLOAD
     return found
@@ -179,7 +141,7 @@ def ms_per_step(r, pred) -> float | None:
     when the step's HLO carries no scope at all (a program without them)."""
     tr = r.trace
     if (r.steps == 0 or not tr["window"] or not tr["devices"]
-            or not scopes_of(r)):
+            or not r.scopes):
         return None
     t0, t1 = tr["window"]
     per_dev = []

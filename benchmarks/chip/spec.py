@@ -12,9 +12,16 @@ from dataclasses import dataclass
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
+# keys a configuration file may leave out, under their published
+# ``config.json`` names where one exists, and what an absent key means
+OPTIONAL = {"attention_bias": False, "mlp_bias": False,
+            "partial_rotary_factor": 1.0, "tie_word_embeddings": False,
+            "position_embedding_type": "rope", "family": "dense"}
+
+
 @dataclass(frozen=True)
 class Model:
-    """A dense decoder as its configuration file states it."""
+    """A decoder as its configuration file states it."""
 
     name: str
     reference: str          # module under references/ that computes it
@@ -27,11 +34,17 @@ class Model:
     head_dim: int
     ff: int
     vocab: int
-    act: str                # "gelu_tanh"
-    norm: str               # "layernorm"
+    act: str                # "gelu_tanh"; "silu" is SwiGLU (gated)
+    norm: str               # "layernorm" or "rmsnorm"
     norm_eps: float
     rope_theta: float
     dtype: str              # parameter and activation type, "bfloat16"
+    attention_bias: bool = OPTIONAL["attention_bias"]      # q, k, v biases
+    mlp_bias: bool = OPTIONAL["mlp_bias"]
+    partial_rotary_factor: float = OPTIONAL["partial_rotary_factor"]
+    tie_word_embeddings: bool = OPTIONAL["tie_word_embeddings"]
+    position_embedding_type: str = OPTIONAL["position_embedding_type"]
+    family: str = OPTIONAL["family"]
 
     @classmethod
     def from_file(cls, cfg: dict) -> "Model":
@@ -45,7 +58,8 @@ class Model:
                    head_dim=cfg["head_dim"], ff=cfg["intermediate_size"],
                    vocab=cfg["vocab_size"], act=cfg["hidden_act"],
                    norm=cfg["norm"], norm_eps=cfg["norm_eps"],
-                   rope_theta=cfg["rope_theta"], dtype=cfg["dtype"])
+                   rope_theta=cfg["rope_theta"], dtype=cfg["dtype"],
+                   **{k: cfg[k] for k in OPTIONAL if k in cfg})
 
     @property
     def d_attn(self) -> int:

@@ -1,36 +1,20 @@
 """Weights from `--seed`, made by the benchmark and handed to both sides.
 
-``canonical(model, key)`` draws every weight of the model in one traced
-function: per-layer leaves stacked on a leading layer axis, plus the
-embedding, the final norm and the head.  The scheme is the program's own
-(truncated normals at 1/sqrt(fan-in); output projections scaled by
-1/sqrt(2 * published depth); norms at gain 1, bias 0), so the model behaves
+Every leaf is named in the leaf table of the configuration's reference
+(``references.Leaf``).  ``canonical(table, key)`` draws every weight of the
+model in one traced function: per-layer leaves stacked on a leading layer
+axis, the others whole, each as the table states it, so the model behaves
 as the program's trainer would start it.  The reference computes on these
-leaves as they are; ``to_program`` lays them out in the program's parameter
-tree (stage-major stacks, padded vocabulary), and ``program_rows`` reads
-one leaf of that layout back.  Nothing here imports the program.
+leaves as they are (``by_layer``); ``to_program`` lays them out in the
+program's parameter tree (stage-major stacks, padded vocabulary), and
+``program_rows`` reads one leaf of that layout back.  Nothing here imports
+the program.
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
 
-LAYER_LEAVES = ("ln1_scale", "ln1_bias", "wq", "wk", "wv", "wo",
-                "ln2_scale", "ln2_bias", "w1", "w2")
-GLOBAL_LEAVES = ("embed", "head", "lnf_scale", "lnf_bias")
-
-# where each leaf lives in the program's parameter tree
-PROGRAM_PATHS = {
-    "ln1_scale": ("ln1", "scale"), "ln1_bias": ("ln1", "bias"),
-    "wq": ("attn", "wq"), "wk": ("attn", "wk"), "wv": ("attn", "wv"),
-    "wo": ("attn", "wo"),
-    "ln2_scale": ("ln2", "scale"), "ln2_bias": ("ln2", "bias"),
-    "w1": ("mlp", "w1"), "w2": ("mlp", "w2"),
-    "embed": ("embed", "table"), "head": ("head", "w"),
-    "lnf_scale": ("final_norm", "scale"), "lnf_bias": ("final_norm", "bias"),
-}
 # program leaves that are structure, not weights: the per-slot residual gate
 # (1 on a layer, 0 on pipeline padding)
 STRUCTURAL = ("gate",)
@@ -41,68 +25,69 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.key(seed % 2**32), seed // 2**32)
 
 
-def shapes(model) -> dict:
-    L, d, ff = model.layers, model.d, model.ff
-    qd, kvd = model.heads * model.head_dim, model.kv_heads * model.head_dim
-    return {
-        "ln1_scale": (L, d), "ln1_bias": (L, d),
-        "wq": (L, d, qd), "wk": (L, d, kvd), "wv": (L, d, kvd),
-        "wo": (L, qd, d),
-        "ln2_scale": (L, d), "ln2_bias": (L, d),
-        "w1": (L, d, ff), "w2": (L, ff, d),
-        "embed": (model.vocab, d), "head": (d, model.vocab),
-        "lnf_scale": (d,), "lnf_bias": (d,),
-    }
+def leaf(table: dict, key, name: str, dtype=jnp.bfloat16):
+    """One leaf as both sides hold it, drawn from ``key`` alone: the fold-in
+    index is its place in the table."""
+    lf = table[name]
+    if lf.std is None:
+        return jnp.full(lf.shape, lf.fill, dtype)
+    k = jax.random.fold_in(key, list(table).index(name))
+    x = jax.random.truncated_normal(k, -2.0, 2.0, lf.shape, jnp.float32)
+    return (x * lf.std).astype(dtype)
 
 
-def leaf(model, key, name: str, dtype=jnp.bfloat16):
-    """One canonical leaf, drawn from ``key`` alone."""
-    shape = shapes(model)[name]
-    if name.endswith("_scale"):
-        return jnp.ones(shape, dtype)
-    if name.endswith("_bias"):
-        return jnp.zeros(shape, dtype)
-    out_scale = 1.0 / math.sqrt(2 * model.published_layers)
-    std = {
-        "wq": 1 / math.sqrt(model.d), "wk": 1 / math.sqrt(model.d),
-        "wv": 1 / math.sqrt(model.d),
-        "wo": out_scale / math.sqrt(model.heads * model.head_dim),
-        "w1": 1 / math.sqrt(model.d), "w2": out_scale / math.sqrt(model.ff),
-        "embed": 0.02, "head": 1 / math.sqrt(model.d),
-    }[name]
-    k = jax.random.fold_in(key, (LAYER_LEAVES + GLOBAL_LEAVES).index(name))
-    return (jax.random.truncated_normal(k, -2.0, 2.0, shape, jnp.float32)
-            * std).astype(dtype)
+def canonical(table: dict, key, dtype=jnp.bfloat16) -> dict:
+    return {name: leaf(table, key, name, dtype) for name in table}
 
 
-def canonical(model, key, dtype=jnp.bfloat16) -> dict:
-    return {name: leaf(model, key, name, dtype)
-            for name in LAYER_LEAVES + GLOBAL_LEAVES}
+def by_layer(held: dict, table: dict) -> dict:
+    """The layout a reference computes on: {"layers": [{leaf: one layer's
+    slice}, ...], global leaf: array, ...}."""
+    per_layer = [n for n, lf in table.items() if lf.per_layer]
+    out = {n: held[n] for n, lf in table.items() if not lf.per_layer}
+    layers = table[per_layer[0]].shape[0]
+    out["layers"] = [{n: held[n][l] for n in per_layer}
+                     for l in range(layers)]
+    return out
 
 
-def program_rows(x, name: str, model, slots: int):
+def program_rows(x, lf, slots: int):
     """A program leaf in canonical layout: [layers, ...] from a stage leaf
     [data, slots, ...] (first replica of each stage), real vocabulary only
     for the embedding and the head."""
-    if name in LAYER_LEAVES:
+    if lf.per_layer:
         return jnp.stack([x[l // slots, l % slots]
-                          for l in range(model.layers)])
-    return x[tuple(slice(0, n) for n in shapes(model)[name])]
+                          for l in range(lf.shape[0])])
+    return x[tuple(slice(0, n) for n in lf.shape)]
 
 
-def norm(x, name: str):
+def norm(x, per_layer: bool):
     """Float32 norm: per layer ([layers]) for a stacked leaf, else whole."""
     sq = jnp.square(x.astype(jnp.float32))
-    if name in LAYER_LEAVES:
+    if per_layer:
         return jnp.sqrt(jnp.sum(sq, axis=tuple(range(1, x.ndim))))
     return jnp.sqrt(jnp.sum(sq))
 
 
-def named(name: str, value) -> dict:
-    """{"L0.wq": float, ...} or {"embed": float} from one ``norm``."""
-    if name in LAYER_LEAVES:
-        return {f"L{l}.{name}": float(v) for l, v in enumerate(value)}
-    return {name: float(value)}
+def norms(x, lf) -> dict:
+    """{"": ``norm``} of one leaf, or {"[part]": ``norm`` of the part}, one
+    per part where the table splits the leaf."""
+    if not lf.parts:
+        return {"": norm(x, lf.per_layer)}
+    return {f"[{p}]": norm(jnp.where(mask, x, 0), lf.per_layer)
+            for p, mask in lf.parts.items()}
+
+
+def named(name: str, values: dict, per_layer: bool) -> dict:
+    """{"L0.wq": float, ...} or {"embed": float} from one ``norms``."""
+    out = {}
+    for part, value in values.items():
+        if per_layer:
+            out.update({f"L{l}.{name}{part}": float(v)
+                        for l, v in enumerate(value)})
+        else:
+            out[name + part] = float(value)
+    return out
 
 
 def _get(tree, path):
@@ -117,38 +102,60 @@ def _set(tree, path, value):
     tree[path[-1]] = value
 
 
-def to_program(canon: dict, struct: dict, *, pp: int, data: int) -> dict:
+def _paths(tree, prefix=()):
+    if not isinstance(tree, dict):
+        return {prefix}
+    return set().union(*(_paths(v, prefix + (k,)) for k, v in tree.items()))
+
+
+def part(lf) -> str:
+    return "stages" if lf.per_layer else "globals"
+
+
+def slots(struct: dict) -> int:
+    """Layer slots per stage of the program's tree."""
+    return _get(struct["stages"], STRUCTURAL).shape[1]
+
+
+def to_program(held: dict, table: dict, struct: dict, *, pp: int,
+               data: int) -> dict:
     """The program's tree {"stages": ..., "globals": ...} from canonical
     leaves.  A stage leaf is [data, slots, ...]; data row i holds stage
     i % pp, whose slot j is layer (i % pp) * slots + j (ghost slots past the
-    last layer are zero with gate 0)."""
+    last layer are zero with gate 0).  The table has to name every weight
+    of the program's tree, and only those."""
+    want = {(part(lf),) + lf.path for lf in table.values()}
+    want.add(("stages",) + STRUCTURAL)
+    have = _paths(struct)
+    if want != have:
+        raise ValueError(f"the reference's leaf table and the program's "
+                         f"tree differ: only in the table "
+                         f"{sorted(want - have)}, only in the program "
+                         f"{sorted(have - want)}")
     out = {}
-    first = _get(struct["stages"], PROGRAM_PATHS["wq"])
-    slots = first.shape[1]
-    n_layers = canon["wq"].shape[0]
+    n_slots = slots(struct)
+    n_layers = next(lf.shape[0] for lf in table.values() if lf.per_layer)
 
     def stack(per_layer, fill):
         rows = []
         for i in range(data):
             s = i % pp
             rows.append(jnp.stack([
-                per_layer(s * slots + j) if s * slots + j < n_layers
-                else fill for j in range(slots)]))
+                per_layer(s * n_slots + j) if s * n_slots + j < n_layers
+                else fill for j in range(n_slots)]))
         return jnp.stack(rows)
 
-    for name in LAYER_LEAVES:
-        path = PROGRAM_PATHS[name]
-        want = _get(struct["stages"], path)
-        leaf = stack(lambda l, n=name: canon[n][l],
-                     jnp.zeros(canon[name].shape[1:], canon[name].dtype))
-        _set(out, ("stages",) + path, leaf.astype(want.dtype))
+    for name, lf in table.items():
+        path = (part(lf),) + lf.path
+        target = _get(struct, path)
+        x = held[name]
+        if lf.per_layer:
+            x = stack(lambda l, x=x: x[l], jnp.zeros(x.shape[1:], x.dtype))
+        else:
+            x = jnp.pad(x, [(0, w - h)
+                            for w, h in zip(target.shape, x.shape)])
+        _set(out, path, x.astype(target.dtype))
     gate = _get(struct["stages"], STRUCTURAL)
     _set(out, ("stages",) + STRUCTURAL, stack(
         lambda l: jnp.ones((), gate.dtype), jnp.zeros((), gate.dtype)))
-    for name in GLOBAL_LEAVES:
-        path = PROGRAM_PATHS[name]
-        want = _get(struct["globals"], path)
-        leaf = canon[name]
-        pad = [(0, w - h) for w, h in zip(want.shape, leaf.shape)]
-        _set(out, ("globals",) + path, jnp.pad(leaf, pad).astype(want.dtype))
     return out

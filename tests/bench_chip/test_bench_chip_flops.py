@@ -1,13 +1,14 @@
 """The benchmark's FLOP and byte counts against hand counts."""
 import os
 import sys
+import types
 
 import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
                                 os.pardir))
 
-from benchmarks.chip import flops, spec  # noqa: E402
+from benchmarks.chip import flops, references, spec  # noqa: E402
 
 
 def test_causal_pairs_are_the_visible_half():
@@ -48,6 +49,21 @@ def test_train_step_flops_of_the_cell():
     assert flops.train_step_flops(m, batch=1, seq=T) == 6 * n * T + attn
     assert flops.train_step_flops(m, batch=1, seq=T) == pytest.approx(
         1.26e14, rel=0.01)
+
+
+def test_routed_experts_count_by_the_share_a_token_visits(monkeypatch):
+    m = spec.load_cell("gpt7b-16k-1chip").model
+    Leaf = references.Leaf
+    table = {
+        "w": Leaf((2, 8, 4), ("w",), per_layer=True, matmul=1.0),
+        # 64 routed experts, 6 of them per token
+        "experts": Leaf((2, 64, 8, 4), ("e",), per_layer=True,
+                        matmul=6 / 64),
+        "embed": Leaf((16, 8), ("embed",), per_layer=False),
+    }
+    monkeypatch.setattr(references, "of", lambda model: types.SimpleNamespace(
+        leaves=lambda model: table))
+    assert flops.matmul_params(m) == 2 * 8 * 4 + 2 * 6 * 8 * 4
 
 
 def test_least_time_takes_the_larger_bound():

@@ -219,15 +219,14 @@ HAND_NS = {
 }
 
 
-def _hlo(text):
-    """What a reader gets with the op_names handed to it."""
-    return {**tracefile.hlo_ops(text), "scopes": scopes.hlo_scopes(text)}
-
-
-def _hand_readings(steps=1, hlo=None, trace=HAND):
+def _hand_readings(steps=1, text=HAND_HLO, trace=HAND, names=None):
+    """What a reader gets for a step of HLO ``text``: its classes and, where
+    ``names`` does not stand in for them, its op_names."""
     return harness.Readings(cell=None, peak=None, chips=2, steps=steps,
                             window_s=1e-7, compiles_in_window=0, trace=trace,
-                            hlo=hlo or _hlo(HAND_HLO))
+                            hlo=tracefile.hlo_ops(text),
+                            scopes=(scopes.hlo_scopes(text) if names is None
+                                    else names))
 
 
 def test_hand_hlo_scopes():
@@ -250,15 +249,21 @@ def test_each_layer_metric_reads_its_scope(metric):
 
 
 @pytest.mark.parametrize("metric", METRICS)
-def test_each_layer_metric_reads_the_live_step(metric, monkeypatch):
-    """Without op_names handed over, a reader takes them from the live
-    executable that defines the most of the instructions the trace names."""
+def test_each_layer_metric_reads_the_live_step(metric):
+    """A reader takes the op_names handed to it with the step
+    (``Readings.scopes``) and no others: the same trace read against
+    another program's op_names reads as a program without scopes."""
     other = "  %fusion.1 = f32[8]{0} fusion(%a), metadata={op_name=\"jit(g)/add\"}\n"
-    monkeypatch.setattr(scopes, "live_hlo_texts", lambda: [other, HAND_HLO])
-    r = _hand_readings(hlo=tracefile.hlo_ops(HAND_HLO))
+    r = _hand_readings()
+    assert r.scopes == scopes.hlo_scopes(HAND_HLO)
     assert _read(metric, r) == pytest.approx(HAND_NS[metric] / 1e6,
                                              rel=1e-12)
-    assert r.hlo["scopes"] == scopes.hlo_scopes(HAND_HLO)
+    # only the host copies, with no layer in either, read alike
+    got = _read(metric, _hand_readings(names=scopes.hlo_scopes(other)))
+    if metric == "offload_wait_ms_per_step":
+        assert got == pytest.approx(UNSCOPED_NS[metric] / 1e6, rel=1e-12)
+    else:
+        assert got is None
 
 
 def test_the_live_step_is_found_in_the_runtime():
@@ -271,10 +276,8 @@ def test_the_live_step_is_found_in_the_runtime():
         with jax.named_scope(scopes.HEAD_LOSS):
             return jnp.sum(jnp.exp(y) * x, axis=0)
 
-    step = jax.jit(f)
     x = jnp.ones((16, 16), jnp.float32)
-    text = step.lower(x).compile().as_text()
-    step(x).block_until_ready()
+    text = jax.jit(f).lower(x).compile().as_text()
     want = scopes.hlo_scopes(text)
     named = {op: v for op, v in want.items() if scopes.layer_of(v)}
     assert {scopes.layer_of(v) for v in named.values()} == {scopes.MLP,
@@ -282,8 +285,8 @@ def test_the_live_step_is_found_in_the_runtime():
     trace = {"window": [0, 100], "async": {}, "host": [],
              "devices": {"/device:TPU:0": [[op, 10 * i, 5] for i, op
                                            in enumerate(sorted(named))]}}
-    r = _hand_readings(hlo=tracefile.hlo_ops(text), trace=trace)
-    assert scopes.scopes_of(r) == want
+    r = _hand_readings(text=text, trace=trace)
+    assert r.scopes == want
     assert scopes.ms_per_step(r, scopes.in_layer(r, scopes.MLP)) \
         == pytest.approx(5e-6 * sum(scopes.layer_of(v) == scopes.MLP
                                     for v in named.values()))
@@ -292,8 +295,8 @@ def test_the_live_step_is_found_in_the_runtime():
 def test_the_harness_readers_find_the_live_step(monkeypatch):
     """A traced run of the harness on the CPU, at a tiny size of the cell:
     the trace is a stand-in (the CPU has no TPU plane) whose events name
-    the step's instructions, and the readers, handed no op_names, read the
-    same numbers as with the op_names of the step's HLO text."""
+    the step's instructions.  The readers are handed the op_names of the
+    step's HLO text, and read the same numbers as from that text."""
     import time
 
     c = spec.load_cell("gpt7b-16k-1chip", ROOT)
@@ -320,7 +323,6 @@ def test_the_harness_readers_find_the_live_step(monkeypatch):
     read = harness.read_metric
 
     def read_metric(name, r):
-        seen.setdefault("handed", dict(r.hlo))
         seen["r"] = r
         return read(name, r)
 
@@ -330,11 +332,14 @@ def test_the_harness_readers_find_the_live_step(monkeypatch):
     monkeypatch.setattr(harness, "use_compile_cache", lambda: None)
     out = harness.run(cell, 2**31 + 7, 0.2, True, t_start=time.perf_counter(),
                       require_tpu=False, log=lambda *_: None)
-    assert "scopes" not in seen["handed"]
+    assert seen["r"].scopes == scopes.hlo_scopes(seen["text"])
+    assert any(map(scopes.layer_of, seen["r"].scopes.values()))
     got = {k: v["value"] for k, v in out["metrics"].items()}
     # the CPU keeps host-memory values on the device: no S(5) copy to wait on
     assert set(got) == set(METRICS) - {"offload_wait_ms_per_step"}
-    given = dataclasses.replace(seen["r"], hlo=_hlo(seen["text"]))
+    given = dataclasses.replace(seen["r"], **{
+        k: getattr(_hand_readings(text=seen["text"]), k)
+        for k in ("hlo", "scopes")})
     for metric, value in got.items():
         assert value == pytest.approx(read(metric, given), rel=1e-12)
 
@@ -347,25 +352,22 @@ UNSCOPED_NS = {"offload_wait_ms_per_step": (5 + 3 + 7 + 9) / 2,
 
 
 @pytest.mark.parametrize("metric", METRICS)
-def test_each_layer_metric_is_silent_without_scoped_events(metric,
-                                                           monkeypatch):
+def test_each_layer_metric_is_silent_without_scoped_events(metric):
     """The readers return None rather than raise where there is nothing to
-    read: no layer scope, no op_names handed over and no live executable
-    that ran the trace, no device events."""
-    plain = _hlo(HAND_HLO.replace("sppo.", "other."))
-    got = _read(metric, _hand_readings(hlo=plain))
+    read: no layer scope, no op_names handed over, no device events."""
+    got = _read(metric, _hand_readings(
+        text=HAND_HLO.replace("sppo.", "other.")))
     if metric in UNSCOPED_NS:
         assert got == pytest.approx(UNSCOPED_NS[metric] / 1e6, rel=1e-12)
     else:
         assert got is None
-    monkeypatch.setattr(scopes, "live_hlo_texts", lambda: [])
-    assert _read(metric, _hand_readings(hlo=tracefile.hlo_ops(HAND_HLO))) \
-        is None
+    assert _read(metric, _hand_readings(names={})) is None
     empty = harness.Readings(cell=None, peak=None, chips=1, steps=0,
                              window_s=0.0, compiles_in_window=0,
                              trace={"window": None, "devices": {},
                                     "async": {}, "host": []},
-                             hlo=_hlo(HAND_HLO))
+                             hlo=tracefile.hlo_ops(HAND_HLO),
+                             scopes=scopes.hlo_scopes(HAND_HLO))
     assert _read(metric, empty) is None
 
 
@@ -424,9 +426,9 @@ def test_unscoped_share_and_top_unscoped():
     assert scopes.top_unscoped(r) == [
         ["dynamic-slice.12", pytest.approx(2 / 2 / 1e6)],
         ["copy-done.13", pytest.approx(1 / 2 / 1e6)]]
-    plain = _hlo(HAND_HLO.replace("sppo.", "other."))
-    assert scopes.unscoped_share(_hand_readings(hlo=plain)) is not None
-    assert scopes.unscoped_share(_hand_readings(hlo=_hlo(""))) is None
+    plain = HAND_HLO.replace("sppo.", "other.")
+    assert scopes.unscoped_share(_hand_readings(text=plain)) is not None
+    assert scopes.unscoped_share(_hand_readings(text="")) is None
 
 
 # ---------------------------------------------------------------------------
@@ -454,12 +456,13 @@ def recorded():
         rec = json.load(f)
     hlo = rec["hlo"]
     hlo = {"kernels": hlo["kernels"], "host_copies": set(hlo["host_copies"]),
-           "collectives": set(hlo["collectives"]), "scopes": hlo["scopes"]}
+           "collectives": set(hlo["collectives"])}
     cell = spec.load_cell("gpt7b-16k-1chip", ROOT)
     return harness.Readings(cell=cell, peak=harness.peak_of("TPU v5 lite"),
                             chips=1, steps=rec["steps"],
                             window_s=rec["window_s"], compiles_in_window=0,
-                            trace=rec["trace"], hlo=hlo)
+                            trace=rec["trace"], hlo=hlo,
+                            scopes=rec["hlo"]["scopes"])
 
 
 @pytest.mark.parametrize("metric", METRICS)
@@ -484,7 +487,7 @@ def test_recorded_scoped_trace_leaves_little_unscoped(recorded):
     # every Pallas kernel is attention; two of the four forwards recompute
     kernels = set(r.hlo["kernels"])
     assert {scopes.layer(r, k) for k in kernels} == {scopes.ATTN}
-    assert sum(scopes.is_recompute(r.hlo["scopes"][k]) for k in kernels) == 2
+    assert sum(scopes.is_recompute(r.scopes[k]) for k in kernels) == 2
 
 
 # ---------------------------------------------------------------------------

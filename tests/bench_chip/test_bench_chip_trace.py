@@ -59,7 +59,7 @@ def test_hlo_classification():
 def test_idle_share_is_the_mean_over_devices():
     r = harness.Readings(cell=None, peak=None, chips=2, steps=1,
                          window_s=1e-7, compiles_in_window=0, trace=HAND,
-                         hlo=tracefile.hlo_ops(HLO))
+                         hlo=tracefile.hlo_ops(HLO), scopes={})
     # device 0 busy 45 of 100, device 1 busy 80 of 100
     assert device_idle_share.read(r) == pytest.approx(100 * (1 - 0.625))
     assert host_copy_ms_per_step.read(r) == pytest.approx(5 / 2 / 1e6)
@@ -116,7 +116,7 @@ def recorded():
     return harness.Readings(cell=cell, peak=harness.peak_of("TPU v5 lite"),
                             chips=1, steps=rec["steps"],
                             window_s=rec["window_s"], compiles_in_window=0,
-                            trace=rec["trace"], hlo=hlo)
+                            trace=rec["trace"], hlo=hlo, scopes={})
 
 
 def test_recorded_chip_trace_reduces_to_fixed_numbers(recorded):
