@@ -48,14 +48,25 @@ def attention_bwd(*, B, H, Hkv, T, S, head_dim, causal=True,
     return 2 * f, q_like + kv_like + 4 * B * T * H
 
 
-def matmul_params(model) -> int:
+def matmul_params(model, pick=None) -> int:
     """Weights that multiply each token, as the reference's leaf table
     states their share (``Leaf.matmul``): the layers' projections and the
     head whole, routed experts by the share of them a token visits.  The
-    embedding is a lookup, and norms and biases are elementwise."""
+    embedding is a lookup, and norms and biases are elementwise.  With
+    ``pick``, only the leaves it accepts."""
     table = references.of(model).leaves(model)
     return round(sum(math.prod(lf.shape) * lf.matmul
-                     for lf in table.values()))
+                     for lf in table.values() if pick is None or pick(lf)))
+
+
+def weights_matmul(model, pick, *, tokens: int) -> tuple:
+    """(FLOPs, bytes) one training step requires of the matmuls of the
+    leaves ``pick`` accepts: 6·N·T (forward 2, backward 4), and their
+    weights read once in bfloat16.  The activations they multiply
+    are not counted: the bytes are a floor, and at thousands of tokens the
+    FLOPs bound the time."""
+    n = matmul_params(model, pick)
+    return 6 * n * tokens, 2 * n
 
 
 def train_step_flops(model, *, batch: int, seq: int) -> int:

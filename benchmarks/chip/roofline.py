@@ -1,7 +1,8 @@
-"""A Pallas kernel's share of its roofline over a traced window."""
+"""Shares of a roofline over a traced window: a Pallas kernel's, and a
+layer's matmuls' against its scope's device time."""
 from __future__ import annotations
 
-from . import flops, tracefile
+from . import flops, scopes, tracefile
 
 
 def share(r, names, *, backward: bool):
@@ -24,3 +25,21 @@ def share(r, names, *, backward: bool):
     least = flops.least_time(f * m.layers / r.chips, b * m.layers / r.chips,
                              r.peak)
     return 100.0 * least / kernel_s
+
+
+def layer_share(r, layer: str, pick):
+    """100 x least time of the matmuls of the leaves ``pick`` accepts
+    (``flops.weights_matmul`` over the step's tokens, split evenly over
+    the chips) / device time of the ops in layer scope ``layer``
+    (``scopes.ms_per_step``), per step: recomputed ops count as time, not
+    as work.  None where the scope has no time or the leaves no FLOPs."""
+    ms = scopes.ms_per_step(r, scopes.in_layer(r, layer))
+    if ms is None or r.peak is None:
+        return None
+    tf = r.cell.traffic
+    f, b = flops.weights_matmul(r.cell.model, pick,
+                                tokens=tf["batch"] * tf["seq_len"])
+    if f == 0:
+        return None
+    least = flops.least_time(f / r.chips, b / r.chips, r.peak)
+    return 100.0 * least / (ms / 1e3)

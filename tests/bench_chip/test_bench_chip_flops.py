@@ -51,6 +51,24 @@ def test_train_step_flops_of_the_cell():
         1.26e14, rel=0.01)
 
 
+def test_layer_matmuls_of_the_cell():
+    from benchmarks.chip.metrics import head_loss_roofline, mlp_roofline
+
+    m = spec.load_cell("gpt7b-16k-1chip").model
+    T = 16384
+    # 4 layers of W1 and W2; the untied head, not the embedding
+    mlp = 4 * 2 * 4096 * 16384
+    assert flops.weights_matmul(m, mlp_roofline.takes, tokens=T) == (
+        6 * T * mlp, 2 * mlp)
+    assert flops.weights_matmul(m, head_loss_roofline.takes, tokens=T) == (
+        6 * T * 4096 * 51200, 2 * 4096 * 51200)
+    assert 6 * T * mlp == pytest.approx(5.28e13, rel=1e-3)
+    # with attention's projections, the layers' and head's matmuls are
+    # every weight the step multiplies
+    attn = 4 * 4 * 4096 * 4096
+    assert mlp + 4096 * 51200 + attn == flops.matmul_params(m)
+
+
 def test_routed_experts_count_by_the_share_a_token_visits(monkeypatch):
     m = spec.load_cell("gpt7b-16k-1chip").model
     Leaf = references.Leaf

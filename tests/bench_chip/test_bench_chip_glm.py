@@ -49,6 +49,7 @@ CONFIG = {
     "partial_rotary_factor": 0.5,
 }
 LIMITS = {"loss_gap": 1e-3, "grad_gap": 2e-2, "change_gap": 4e-2}
+GLM_EPS = 1.5625e-7      # GLM-4's RMSNorm eps; the program runs 1e-6
 
 
 @pytest.fixture(scope="module")
@@ -98,6 +99,32 @@ def test_step_mfu_counts_three_mlp_matrices(tiny):
                                           + 3 * d * ff) + d * V
 
 
+# GLM-4-9B at published widths, 4 of its 40 layers
+GLM_WIDTHS = dict(CONFIG, num_hidden_layers=4, hidden_size=4096,
+                  num_attention_heads=32, num_key_value_heads=2, head_dim=128,
+                  intermediate_size=13696, vocab_size=151552,
+                  norm_eps=GLM_EPS)
+
+
+def test_layer_rooflines_count_glm_mlp_and_head(monkeypatch):
+    from benchmarks.chip.metrics import head_loss_roofline, mlp_roofline
+
+    monkeypatch.setitem(sys.modules,
+                        f"benchmarks.chip.references.{REFERENCE}", glm_double)
+    m = spec.Model.from_file(GLM_WIDTHS)
+    T = 16384
+    # the gated MLP's gate, up and down; the head d x 151552
+    mlp = 4 * 3 * 4096 * 13696
+    head = 4096 * 151552
+    assert flops.weights_matmul(m, mlp_roofline.takes, tokens=T) == (
+        6 * T * mlp, 2 * mlp)
+    assert flops.weights_matmul(m, head_loss_roofline.takes, tokens=T) == (
+        6 * T * head, 2 * head)
+    # the head is about 37% of the step's model FLOPs
+    assert 6 * T * head / flops.train_step_flops(m, batch=1, seq=T) \
+        == pytest.approx(0.37, abs=0.01)
+
+
 def test_program_config_takes_the_file():
     cfg = harness.program_config(spec.Model.from_file(CONFIG))
     assert (cfg.act, cfg.norm, cfg.qkv_bias, cfg.rope_fraction) == (
@@ -105,16 +132,45 @@ def test_program_config_takes_the_file():
     assert (cfg.n_layers, cfg.d_model, cfg.n_kv_heads) == (2, 64, 2)
 
 
-@pytest.mark.parametrize("kind", ["rmsnorm", "layernorm"])
-def test_program_norm_eps_is_the_eps_the_norms_run_at(kind):
-    # a row whose variance is the eps read: the output's size shows the eps
-    # that ``apply_norm`` runs at, whatever it passes
-    import jax.numpy as jnp
-
+def _norm_at(monkeypatch, eps):
+    """The program's norms made to run at ``eps``, as a program that passes
+    its configuration's eps to ``apply_norm`` would, with the functions'
+    defaults left as they are."""
     harness._import_program()
     from repro.models import layers
 
-    eps = harness.program_norm_eps(types.SimpleNamespace(norm=kind))
+    rms = layers.rms_norm
+
+    def apply_norm(x, p, kind):
+        assert kind == "rmsnorm"
+        return rms(x, p["scale"], eps)
+
+    monkeypatch.setattr(layers, "apply_norm", apply_norm)
+
+
+EPS_CASES = {"rmsnorm": ("rmsnorm", 1e-6, None),
+             "layernorm": ("layernorm", 1e-5, None),
+             "patched": ("rmsnorm", GLM_EPS, GLM_EPS)}
+
+
+@pytest.mark.parametrize("case", list(EPS_CASES))
+def test_program_norm_eps_is_the_eps_the_norms_run_at(case, monkeypatch):
+    # the eps read is measured through the program's model: the norms'
+    # defaults (1e-6, 1e-5) where nothing passes another, and the eps a
+    # patched norm runs at where one does.  A row whose mean square is the
+    # eps read then comes out at 1 / sqrt(2) of its size
+    import jax.numpy as jnp
+
+    from repro.configs.base import get_config
+    from repro.models import layers
+
+    kind, want, patched = EPS_CASES[case]
+    if patched is not None:
+        _norm_at(monkeypatch, patched)
+    arch = {"rmsnorm": "glm4-9b", "layernorm": "sppo-gpt-7b"}[kind]
+    cfg = dataclasses.replace(get_config(arch), d_model=64)
+    eps = harness.program_norm_eps(cfg)
+    assert eps == pytest.approx(want, rel=1e-4)
     a = math.sqrt(eps)
     x = jnp.array([[a, -a] * 4], jnp.float32)
     p = {"scale": jnp.zeros(8) if kind == "rmsnorm" else jnp.ones(8),
@@ -122,6 +178,18 @@ def test_program_norm_eps_is_the_eps_the_norms_run_at(kind):
     y = np.asarray(layers.apply_norm(x, p, kind))
     np.testing.assert_allclose(np.abs(y), a / math.sqrt(a * a + eps),
                                rtol=1e-5)
+
+
+def test_program_config_holds_the_measured_eps(monkeypatch):
+    glm = dict(CONFIG, norm_eps=GLM_EPS)
+    with pytest.raises(ValueError, match="program has norm eps=.*states "
+                       "norm_eps=1.5625e-07"):
+        harness.program_config(spec.Model.from_file(glm))
+    _norm_at(monkeypatch, GLM_EPS)
+    harness.program_config(spec.Model.from_file(glm))
+    with pytest.raises(ValueError, match="program has norm eps=.*states "
+                       "norm_eps=1e-06"):
+        harness.program_config(spec.Model.from_file(CONFIG))
 
 
 with open(os.path.join(ROOT, "benchmarks", "chip", "configs",

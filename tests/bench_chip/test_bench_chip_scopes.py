@@ -471,6 +471,36 @@ def test_recorded_scoped_trace_reads_fixed_numbers(recorded, metric):
                                                     rel=1e-9)
 
 
+# the layers' rooflines read from it: 6·T·N at 197e12 FLOP/s over the
+# layer's time, N the MLP's 4·2·4096·16384 and the head's 4096·51200
+RECORDED_ROOFLINE = {"mlp_roofline": 65.32168431947638,
+                     "head_loss_roofline": 44.57943110049715}
+ROOFLINE_OF = {"mlp_roofline": ("mlp_ms_per_step", 4 * 2 * 4096 * 16384),
+               "head_loss_roofline": ("head_loss_ms_per_step", 4096 * 51200)}
+
+
+@pytest.mark.parametrize("metric", sorted(RECORDED_ROOFLINE))
+def test_recorded_scoped_trace_reads_layer_rooflines(recorded, metric):
+    got = _read(metric, recorded)
+    assert got == pytest.approx(RECORDED_ROOFLINE[metric], rel=1e-9)
+    assert 0 < got <= 100
+    ms, n = ROOFLINE_OF[metric]
+    least_s = 6 * n * 16384 / recorded.peak["bf16_flops_per_s"]
+    assert got == pytest.approx(100 * least_s / (RECORDED_MS[ms] / 1e3),
+                                rel=1e-8)
+
+
+@pytest.mark.parametrize("metric", sorted(RECORDED_ROOFLINE))
+def test_layer_rooflines_are_silent_without_their_layer(recorded, metric):
+    # no peak for the device; no op of the layer; no step in the window
+    assert _read(metric, _hand_readings()) is None
+    other = dataclasses.replace(recorded, scopes={
+        op: name.replace("sppo.", "other.")
+        for op, name in recorded.scopes.items()})
+    assert _read(metric, other) is None
+    assert _read(metric, dataclasses.replace(recorded, steps=0)) is None
+
+
 def test_recorded_scoped_trace_leaves_little_unscoped(recorded):
     r = recorded
     assert scopes.unscoped_share(r) == pytest.approx(RECORDED_UNSCOPED,
